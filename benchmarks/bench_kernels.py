@@ -9,13 +9,14 @@ reports both speedups, the residual fraction, bit-identity of the fused
 launch against the engine oracle ``bank.update_block_fused``, and the
 roofline columns (achieved vs peak bytes/s, arithmetic intensity) from
 the sketch-ingest cost model (``repro.roofline.model``) against the
-hardware preset for the detected backend (``repro.platform``). Results
+preset for the attached device's ``device_kind`` (``repro.platform``).
+Results
 are written to ``BENCH_kernels.json`` at the repo root so the perf
 trajectory is machine-readable across PRs.
 
-Wall-times here are CPU interpret-mode numbers — correctness and
-relative-shape trends only (``peak_fraction`` likewise reads against
-the cpu preset); the TPU story is the roofline analysis (DESIGN.md §7).
+Wall-times from a CPU run are interpret-mode numbers — correctness and
+relative-shape trends only; such a run has no device peaks, so its
+``peak_fraction`` column reads "not measured".
 """
 from __future__ import annotations
 
@@ -30,6 +31,7 @@ import jax.numpy as jnp
 from benchmarks.common import (
     UNIVERSE_BITS,
     csv_print,
+    device_hw,
     dist_stream,
     min_time,
     write_bench_json,
@@ -57,24 +59,23 @@ def bench_sketch_update(runs: int = 3, shapes=SKETCH_SHAPES):
         sketch_block_update_serial,
     )
     from repro import sketch as js
-    from repro.platform import hw_config
     from repro.roofline.model import sketch_ingest_cost, sketch_roofline
     from repro.sketch import bank as bk
 
     # end-to-end fused client: route (packed single sort — items live in
     # [0, 2^UNIVERSE_BITS)) + prep + ONE tiled kernel launch, all one jit
-    # program; interpret=True pinned so fused vs split is an interpret-
-    # comparable measurement on CPU
+    # program; interpret is platform-resolved like the split path's, so
+    # fused vs split compare like for like on either backend
     router = bk.HashShardRouter(1, UNIVERSE_BITS)
 
     @jax.jit
     def fused_ingest(state, items, weights):
         bank1 = jax.tree.map(lambda x: x[None], state)
         ri, rw = router.route_dense(items, weights)
-        out = sketch_block_update_fused(bank1, ri, rw, 2, True)
+        out = sketch_block_update_fused(bank1, ri, rw, 2)
         return jax.tree.map(lambda x: x[0], out)
 
-    hw = hw_config()
+    hw = device_hw()
     rows = []
     for dist in SKETCH_DISTRIBUTIONS:
         for k, block in shapes:

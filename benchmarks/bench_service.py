@@ -35,6 +35,7 @@ import numpy as np
 from benchmarks.common import (
     UNIVERSE_BITS,
     csv_print,
+    device_hw,
     min_time,
     mixed_traffic,
     write_bench_json,
@@ -272,13 +273,12 @@ def _fused_vs_sessions(tenants: int, k_per_tenant: int, block: int,
 
 def _roofline_row(tenants: int, k_per_tenant: int, block: int,
                   t_fused: float, n_blocks: int) -> list:
-    from repro.platform import hw_config
     from repro.roofline.model import sketch_ingest_cost, sketch_roofline
 
     rows = tenants  # S=1 at the bench shape
     cost = sketch_ingest_cost(num_rows=rows, k=k_per_tenant, block=block)
     wall = t_fused / max(n_blocks, 1)
-    roof = sketch_roofline(cost, wall, hw_config())
+    roof = sketch_roofline(cost, wall, device_hw())
     return [tenants, rows, k_per_tenant, block, wall * 1e3,
             n_blocks * block / max(t_fused, 1e-12),
             roof["achieved_bytes_per_s"], roof["peak_fraction"],

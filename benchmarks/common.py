@@ -21,6 +21,17 @@ UNIVERSE = 1 << 16
 UNIVERSE_BITS = 16
 
 
+def device_hw():
+    """Roofline preset of the attached accelerator, or None on the CPU.
+
+    A CPU run has no device peaks to read against, so its roofline
+    columns say "not measured" (``roofline.model.sketch_roofline``).
+    """
+    from repro.platform import has_accelerator, hw_config
+
+    return hw_config() if has_accelerator() else None
+
+
 def dist_stream(dist: str, n_insert: int, delete_ratio: float = 0.5,
                 *, seed: int = 0, universe: int = UNIVERSE,
                 delete_pattern: str = "random",

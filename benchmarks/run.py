@@ -89,6 +89,9 @@ def main() -> int:
                     help="tiny shapes, no JSON artifacts (CI bitrot guard)")
     args = ap.parse_args()
 
+    from repro.platform import enable_compile_cache
+
+    enable_compile_cache()
     names = [args.only] if args.only else list(BENCHES)
     t_all = time.time()
     failed = []
@@ -97,14 +100,18 @@ def main() -> int:
         print(f"\n{'='*70}\n== {name}: {desc}\n{'='*70}", flush=True)
         t0 = time.time()
         if name == "compression":
-            # needs emulated devices: run in a subprocess with XLA_FLAGS
-            # so this process keeps its single-device view
+            # needs 8 emulated CPU devices: run in a subprocess with
+            # XLA_FLAGS so this process keeps its own device view. The
+            # bench counts bytes, not time, and the child is held to the
+            # CPU: where this process holds a chip, a child reaching for
+            # it would fail or hang.
             import os
             import subprocess
 
             from repro.platform import xla_host_device_flags
             env = dict(os.environ)
             env["XLA_FLAGS"] = xla_host_device_flags(8)
+            env["JAX_PLATFORMS"] = "cpu"
             out = subprocess.run(
                 [sys.executable, "-m", mod_name], env=env,
                 capture_output=True, text=True, timeout=600,

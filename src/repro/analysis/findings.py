@@ -97,6 +97,29 @@ def relpath(path: str) -> str:
                            repo_root()).replace(os.sep, "/")
 
 
+def call_body(eqn):
+    """The body jaxpr of a call-like equation, or None.
+
+    Matches on structure, not on the primitive's name: any equation
+    carrying one sub-jaxpr under ``jaxpr`` or ``call_jaxpr`` (``jit``,
+    which older JAX named ``pjit``, ``closed_call``, ``remat``,
+    ``custom_jvp_call``, ...). Callers dispatch ``while``/``scan``/
+    ``cond`` first, since their sub-jaxprs need loop or branch handling.
+    A body whose arity differs from the equation's (a ``pallas_call``
+    kernel reads and writes refs) is not a call and gives None.
+    """
+    sub = eqn.params.get("jaxpr")
+    if sub is None:
+        sub = eqn.params.get("call_jaxpr")
+    if sub is None:
+        return None
+    inner = sub.jaxpr if hasattr(sub, "jaxpr") else sub
+    if not hasattr(inner, "eqns") or len(inner.invars) != len(eqn.invars) \
+            or len(inner.outvars) != len(eqn.outvars):
+        return None
+    return inner
+
+
 def default_baseline_path() -> str:
     return os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "baseline.json")

@@ -41,8 +41,9 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 import jax
+from jax.extend.core import Literal
 
-from .findings import Finding, relpath
+from .findings import Finding, call_body, relpath
 
 INT32_MIN = -(2**31)
 INT32_MAX = 2**31 - 1
@@ -205,8 +206,8 @@ class _Analyzer:
             if d is None:
                 continue
             lo_v = hi_v = None
-            if d.primitive.name == "pjit" and d.params.get(
-                    "name") == "clip" and len(d.invars) == 3:
+            if d.params.get("name") == "clip" and call_body(d) is not None \
+                    and len(d.invars) == 3:
                 _, lo_v, hi_v = d.invars
             elif d.primitive.name == "min" and len(d.invars) == 2:
                 # inlined clip: min(hi, max(b, lo)) in either operand order
@@ -228,7 +229,7 @@ class _Analyzer:
 
     @staticmethod
     def _is_lit(v) -> bool:
-        return isinstance(v, jax.core.Literal)
+        return isinstance(v, Literal)
 
     def _is_headroom(self, v, a, minmax: str, const: int, defs) -> bool:
         """Is ``v`` = const - min/max(a, 0) (possibly via broadcast)?"""
@@ -291,7 +292,7 @@ class _Analyzer:
             lhs, rhs = d.invars
             if not self._same_var(lhs, a_v, defs):
                 continue
-            if isinstance(rhs, jax.core.Literal):
+            if isinstance(rhs, Literal):
                 n_iv = const_ival(rhs.val)
             else:
                 n_iv = env.get(id(self._skip_shape_ops(rhs, defs)))
@@ -330,7 +331,7 @@ class _Analyzer:
         defs: Dict[int, Any] = {}
 
         def read(v) -> Ival:
-            if isinstance(v, jax.core.Literal):
+            if isinstance(v, Literal):
                 return const_ival(v.val)
             return env.get(id(v), dtype_ival(v.aval))
 
@@ -557,11 +558,6 @@ class _Analyzer:
             lo = min(max(x.lo, lo_i.lo), hi_i.lo)
             hi = min(max(x.hi, lo_i.hi), hi_i.hi)
             return [Ival(lo, hi, x.wtag, x.psrc, x.rsum)]
-        if p in ("pjit", "closed_call", "core_call", "custom_jvp_call",
-                 "custom_vjp_call", "remat", "checkpoint"):
-            sub = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
-            inner = sub.jaxpr if hasattr(sub, "jaxpr") else sub
-            return self.run_sub(inner, ins, report)
         if p == "while":
             return self._while(eqn, ins, report)
         if p == "scan":
@@ -574,6 +570,9 @@ class _Analyzer:
                 outs = bo if outs is None else [
                     a.join(b) for a, b in zip(outs, bo)]
             return outs
+        inner = call_body(eqn)
+        if inner is not None:
+            return self.run_sub(inner, ins, report)
         if p in ("random_bits", "random_split", "random_wrap",
                  "random_unwrap", "random_seed"):
             return [dtype_ival(v.aval) for v in eqn.outvars]
@@ -606,7 +605,7 @@ class _Analyzer:
         seen = set()
         while stack:
             v = stack.pop()
-            if id(v) in seen or isinstance(v, jax.core.Literal):
+            if id(v) in seen or isinstance(v, Literal):
                 continue
             seen.add(id(v))
             d = defs.get(id(v))
@@ -641,7 +640,7 @@ class _Analyzer:
             out = list(carry_iv)
 
             def val_of(idx, var):
-                if isinstance(var, jax.core.Literal):
+                if isinstance(var, Literal):
                     return const_ival(var.val)
                 return civ[idx] if idx is not None else None
 
@@ -649,10 +648,10 @@ class _Analyzer:
                 for ia, a, ib, b in recs:
                     a_iv = val_of(ia, a) if ia is not None else (
                         const_ival(a.val) if isinstance(
-                            a, jax.core.Literal) else None)
+                            a, Literal) else None)
                     b_iv = val_of(ib, b) if ib is not None else (
                         const_ival(b.val) if isinstance(
-                            b, jax.core.Literal) else None)
+                            b, Literal) else None)
                     # refine only scalar carries (vector compares reduce
                     # through reduce_and/or and aren't followed here)
                     k = cn  # carry region starts at index cn in cond invars

@@ -34,8 +34,9 @@ from typing import Dict, List, Set, Tuple
 import numpy as np
 
 import jax
+from jax.extend.core import Literal
 
-from .findings import Finding, relpath
+from .findings import Finding, call_body, relpath
 
 _SHAPE_OPS = frozenset({
     "broadcast_in_dim", "reshape", "squeeze", "expand_dims", "transpose",
@@ -64,7 +65,7 @@ def _site(eqn, entry: str) -> Tuple[str, int]:
 
 
 def _is_lit(v) -> bool:
-    return isinstance(v, jax.core.Literal)
+    return isinstance(v, Literal)
 
 
 def _lit_value(v):
@@ -188,15 +189,6 @@ class _Taint:
                     tainted.add(id(eqn.outvars[0]))
                 continue
 
-            if p in ("pjit", "closed_call", "custom_jvp_call",
-                     "custom_vjp_call", "remat", "checkpoint"):
-                sub = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
-                inner = sub.jaxpr if hasattr(sub, "jaxpr") else sub
-                out_t = _Taint.run_child(self, inner, ins_t)
-                for ov, t in zip(eqn.outvars, out_t):
-                    if t:
-                        tainted.add(id(ov))
-                continue
             if p == "while":
                 cn = eqn.params.get("cond_nconsts", 0)
                 bn = eqn.params.get("body_nconsts", 0)
@@ -239,6 +231,13 @@ class _Taint:
                 for br in eqn.params["branches"]:
                     bt = _Taint.run_child(self, br.jaxpr, ins_t[1:])
                     out_t = [a or b for a, b in zip(out_t, bt)]
+                for ov, t in zip(eqn.outvars, out_t):
+                    if t:
+                        tainted.add(id(ov))
+                continue
+            inner = call_body(eqn)
+            if inner is not None:
+                out_t = _Taint.run_child(self, inner, ins_t)
                 for ov, t in zip(eqn.outvars, out_t):
                     if t:
                         tainted.add(id(ov))

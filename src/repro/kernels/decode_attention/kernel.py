@@ -20,10 +20,13 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.platform import resolve_interpret
 
 F32 = jnp.float32
 NEG_INF = -1e9
@@ -39,12 +42,12 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, ctx_ref, mass_ref, *, scale):
     q = q_ref[0, 0].astype(F32)                    # (G, hd)
     k = k_ref[0, 0].astype(F32)                    # (C, hd) this kv head
     v = v_ref[0, 0].astype(F32)
-    ok = valid_ref[0] != 0                         # (C,)
+    ok = valid_ref[0] != 0                         # (1, C)
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=F32
     ) * scale                                       # (G, C)
-    s = jnp.where(ok[None, :], s, NEG_INF)
+    s = jnp.where(ok, s, NEG_INF)
     m = s.max(axis=1, keepdims=True)
     p = jnp.exp(s - m)
     denom = p.sum(axis=1, keepdims=True)
@@ -55,18 +58,27 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, ctx_ref, mass_ref, *, scale):
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=F32
     )                                               # (G, hd)
     ctx_ref[0, 0] = ctx.astype(ctx_ref.dtype)
-    mass_ref[0] = mass_ref[0] + p.sum(axis=0)       # accumulate over kv heads
+    # accumulate over kv heads
+    mass_ref[0] = mass_ref[0] + p.sum(axis=0, keepdims=True)
 
 
 def decode_attention_kernel(
     q: jax.Array,        # (B, KV, G, hd)
     k_cache: jax.Array,  # (B, KV, C, hd)  — kv-head-major layout
     v_cache: jax.Array,  # (B, KV, C, hd)
-    valid: jax.Array,    # (B, C) int32
+    valid: jax.Array,    # (B, 1, C) int32
     *,
     scale: float = 0.0,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
+    """Returns ``(ctx (B, KV, G, hd), mass (B, 1, C))``.
+
+    ``valid`` and ``mass`` carry a unit middle axis so their blocks are
+    ``(1, 1, C)``: the last two block dims then equal the array's, which
+    the TPU's (8, 128) tiling rule accepts for any B (a ``(1, C)`` block
+    of a ``(B, C)`` array does not).
+    """
+    interpret = resolve_interpret(interpret)
     B, KV, G, hd = q.shape
     C = k_cache.shape[2]
     scale = scale or 1.0 / math.sqrt(hd)
@@ -78,15 +90,15 @@ def decode_attention_kernel(
             pl.BlockSpec((1, 1, G, hd), lambda b, k: (b, k, 0, 0)),
             pl.BlockSpec((1, 1, C, hd), lambda b, k: (b, k, 0, 0)),
             pl.BlockSpec((1, 1, C, hd), lambda b, k: (b, k, 0, 0)),
-            pl.BlockSpec((1, C), lambda b, k: (b, 0)),
+            pl.BlockSpec((1, 1, C), lambda b, k: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, G, hd), lambda b, k: (b, k, 0, 0)),
-            pl.BlockSpec((1, C), lambda b, k: (b, 0)),
+            pl.BlockSpec((1, 1, C), lambda b, k: (b, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, KV, G, hd), v_cache.dtype),
-            jax.ShapeDtypeStruct((B, C), F32),
+            jax.ShapeDtypeStruct((B, 1, C), F32),
         ],
         interpret=interpret,
     )(q, k_cache, v_cache, valid)

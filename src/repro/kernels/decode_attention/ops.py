@@ -39,7 +39,7 @@ def decode_attention(
     kt = k_cache.transpose(0, 2, 1, 3)  # (B, KV, C, hd)
     vt = v_cache.transpose(0, 2, 1, 3)
     ctx, mass = decode_attention_kernel(
-        q, kt, vt, valid.astype(jnp.int32),
+        q, kt, vt, valid.astype(jnp.int32)[:, None, :],
         scale=1.0 / (hd_t ** 0.5), interpret=interpret,
     )
-    return ctx[..., :hd_t], mass
+    return ctx[..., :hd_t], mass[:, 0, :]

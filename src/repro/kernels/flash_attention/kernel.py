@@ -19,11 +19,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.platform import resolve_interpret
 
 F32 = jnp.float32
 NEG_INF = -1e30
@@ -90,8 +93,9 @@ def flash_attention_kernel(
     bq: int = 128,
     bkv: int = 128,
     scale: float = 0.0,       # 0 -> 1/sqrt(hd); pass explicitly when hd padded
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
+    interpret = resolve_interpret(interpret)
     BH, S, hd = q.shape
     T = k.shape[1]
     bq = min(bq, S)

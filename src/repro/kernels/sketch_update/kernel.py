@@ -18,8 +18,16 @@ empty fill, the unit-weight water-fill and the lockstep residual
 tournament. Phase-1 *prep* (sorts, match census — reads only ids,
 does not lower in Mosaic) stays in XLA via ``bank.phase1_dense_prep``
 and feeds the kernel a per-cell delta; prep + launch trace as one jit
-program. Row independence + active-mask freezing make any row_tile
-bit-identical to the engine oracle ``bank.update_block_fused``.
+program. The tile body is written in what Mosaic lowers (lane rolls,
+compares, selects, lane reductions); it compiles for TPU v5e
+(tests/test_tpu_compile.py). Row independence + active-mask freezing
+make any row_tile bit-identical to the engine oracle
+``bank.update_block_fused``.
+
+The other three kernels below run in interpret mode only: Mosaic
+refuses ``dynamic_slice`` (``sketch_residual_kernel``), one-dimensional
+gathers (``sketch_residual_kernel_banked``) and the serial kernel's
+non-float32 reductions. No user spec selects them.
 
 ``sketch_residual_kernel`` — the two-phase split path's phase 2. The
 wrapper (ops.py) segment-aggregates the block and scatter-adds all
@@ -52,17 +60,16 @@ Weights are signed: w > 0 weighted insert, w < 0 weighted delete
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.sketch.phases import (
-    fill_empty_slots,
-    residual_phase,
-    waterfill_unit_inserts,
-)
-from repro.sketch.state import LANES, sat_add
+from repro.platform import resolve_interpret
+from repro.sketch.phases import residual_phase
+from repro.sketch.state import LANES, VARIANT_LAZY, sat_add
 
 _INT_MAX = 2**31 - 1  # python ints: pallas kernels must not close over arrays
 EMPTY = -1
@@ -71,6 +78,224 @@ EMPTY = -1
 # ---------------------------------------------------------------------------
 # Fused tiled kernel: phases 1-2 on VMEM-resident (row_tile, K) tiles
 # ---------------------------------------------------------------------------
+# Mosaic lowers none of cumsum, one-dimensional gathers, int32
+# argmin/argmax or a flattening reshape, so the tile body below spells
+# the engine's phases with what it does lower: lane rolls
+# (``pltpu.roll``), compares, selects and lane reductions. Every row of a
+# tile reads its own (B,) stream row, so no index ever crosses rows.
+
+
+def _lanes(shape) -> jax.Array:
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+
+
+def _roll_left(x, s: int):
+    """Lane j reads lane (j + s) mod L (a static circular shift)."""
+    L = x.shape[1]
+    return pltpu.roll(x, (L - s) % L, 1)
+
+
+def _roll_right(x, s: int):
+    """Lane j reads lane (j - s) mod L."""
+    return pltpu.roll(x, s % x.shape[1], 1)
+
+
+def _excl_count(mask) -> jax.Array:
+    """Per lane: how many lanes before it (same row) hold ``mask``.
+
+    The exclusive prefix sum by log-step shifted adds (``cumsum`` does
+    not lower in Mosaic).
+    """
+    x = mask.astype(jnp.int32)
+    lane = _lanes(x.shape)
+    c = x
+    s = 1
+    while s < x.shape[1]:
+        c = c + jnp.where(lane >= s, _roll_right(c, s), 0)
+        s *= 2
+    return c - x
+
+
+def _shift_left_rows(x, amt):
+    """Row r of ``x`` read from lane ``j + amt[r]`` (circular).
+
+    ``amt`` is an (RT, 1) column in [0, L]; one select per bit of it.
+    """
+    L = x.shape[1]
+    b = 1
+    while b < L:
+        x = jnp.where((amt & b) != 0, _roll_left(x, b), x)
+        b *= 2
+    return x
+
+
+def _expand(vals, sel, width: int):
+    """Spread consecutive values over the set lanes of ``sel``.
+
+    ``vals``: (RT, L) arrays whose lane n holds the n-th value; ``sel``:
+    (RT, width) mask. Returns, per array, an (RT, width) array whose
+    lane j holds value number ``#(sel before j)`` wherever ``sel[j]``
+    (anything elsewhere): the gather ``v[excl_count(sel)]`` without a
+    gather. The n-th value moves right by ``d_n`` = the unset lanes
+    before its target, which never decreases in n, so log-step moves
+    never collide: ``d`` is first carried from the targets back to the
+    sources (left moves, low bit first), then the values move right
+    (high bit first).
+    """
+    L = vals[0].shape[1]
+    if L >= width:
+        vals = [v[:, :width] for v in vals]
+    else:
+        vals = [jnp.concatenate(
+            [v, jnp.zeros((v.shape[0], width - L), v.dtype)], axis=1)
+            for v in vals]
+    sel = sel.astype(jnp.int32)
+    d = _excl_count(1 - sel)            # unset lanes before each lane
+    bits = []
+    b = 1
+    while b < width:
+        bits.append(b)
+        b *= 2
+    live = sel                          # carry d from targets to sources
+    for b in bits:
+        move = live * ((d & b) != 0)
+        inc = _roll_left(move, b)
+        d = jnp.where(inc != 0, _roll_left(d, b), d)
+        live = inc + live * (1 - move)
+    # live now marks the first #sel lanes (the sources), each holding d
+    for b in reversed(bits):
+        move = live * ((d & b) != 0)
+        inc = _roll_right(move, b)
+        vals = [jnp.where(inc != 0, _roll_right(v, b), v) for v in vals]
+        d = jnp.where(inc != 0, _roll_right(d, b), d)
+        live = inc + live * (1 - move)
+    return vals
+
+
+def _argmin_first(x, lane):
+    """(min, first lane holding it) per row, both (RT, 1)."""
+    m = jnp.min(x, axis=1, keepdims=True)
+    return m, jnp.min(jnp.where(x == m, lane, x.shape[1]), axis=1,
+                      keepdims=True)
+
+
+def _argmax_first(x, lane):
+    m = jnp.max(x, axis=1, keepdims=True)
+    return m, jnp.min(jnp.where(x == m, lane, x.shape[1]), axis=1,
+                      keepdims=True)
+
+
+def _any(mask) -> jax.Array:
+    """Scalar: does any row of an (RT, 1) mask hold? Reduced over a
+    lane-dense broadcast, so no padding lane of the column can vote."""
+    m = jnp.broadcast_to(mask.astype(jnp.int32), (mask.shape[0], LANES))
+    return jnp.max(m) > 0
+
+
+def _fill_tile(ids, counts, errors, uids, nets, i0, off):
+    """Phase 1.5 (``phases.fill_empty_slots``): the j-th empty slot takes
+    the j-th consumed insert, at stream lanes ``off, off + 1, ...``."""
+    empty = ids == -1
+    take = empty & (_excl_count(empty) < i0)
+    u, n = _expand([_shift_left_rows(uids, off), _shift_left_rows(nets, off)],
+                   empty, ids.shape[1])
+    return (jnp.where(take, u, ids), jnp.where(take, n, counts),
+            jnp.where(take, 0, errors))
+
+
+def _waterfill_tile(ids, counts, errors, uu, m):
+    """Phase 1.75 (``phases.waterfill_unit_inserts``) for a tile of rows:
+    the same water level, pop counts and pop positions, with the two
+    position runs read by ``_expand`` instead of a gather."""
+    B = uu.shape[1]
+
+    def n_leq(x):
+        d = jnp.clip(sat_add(x, jnp.negative(counts)), 0, m)
+        return jnp.where(counts <= x, d + 1, 0)
+
+    lo = jnp.min(counts, axis=1, keepdims=True)
+    hi = sat_add(lo, m)
+
+    def probe(_, lh):
+        lo, hi = lh
+        mid = sat_add(lo, sat_add(hi, jnp.negative(lo)) // 2)
+        ge = jnp.sum(n_leq(mid), axis=1, keepdims=True) >= m
+        return jnp.where(ge, lo, sat_add(mid, 1)), jnp.where(ge, mid, hi)
+
+    T, _ = jax.lax.fori_loop(0, B.bit_length() + 1, probe, (lo, hi))
+    f_tm1 = jnp.sum(n_leq(T - 1), axis=1, keepdims=True)
+    r = m - f_tm1
+    elig = counts <= T
+    extra = elig & (_excl_count(elig) < r)
+    t = jnp.where(counts <= T - 1,
+                  jnp.clip(sat_add(T, jnp.negative(counts)), 0, m), 0) \
+        + extra.astype(jnp.int32)
+    evicted = t > 0
+    new_counts = sat_add(counts, t)
+    f_tm2 = jnp.sum(jnp.where(counts < T - 1,
+                              jnp.clip(sat_add(T - 1, jnp.negative(counts)),
+                                       0, m), 0), axis=1, keepdims=True)
+    under = counts <= T - 1
+    K = ids.shape[1]
+    (at_line,) = _expand([_shift_left_rows(uu, f_tm2)], under, K)
+    (at_extra,) = _expand([_shift_left_rows(uu, f_tm1)], elig, K)
+    new_ids = jnp.where(extra, at_extra, jnp.where(evicted, at_line, ids))
+    return (new_ids, new_counts,
+            jnp.where(evicted, new_counts - 1, errors))
+
+
+def _residual_tile(ids2, cnt2, err2, uids, nets, start, n_ins, w_del,
+                   variant: int):
+    """Phase 2 (``bank.residual_phase_banked``) for a tile of rows: the
+    row's i-th insert is read by a lane one-hot over its stream row, and
+    the min/max slot is the first lane equal to the row's min/max."""
+    RT, K = ids2.shape
+    B = uids.shape[1]
+    lane = _lanes((RT, K))
+    lane_b = _lanes((RT, B))
+
+    def ins_cond(carry):
+        return _any(carry[0] < n_ins)
+
+    def ins_step(carry):
+        i, ids2, cnt2, err2 = carry
+        active = i < n_ins
+        at = lane_b == jnp.clip(i, 0, B - 1)
+        uid = jnp.sum(jnp.where(at, uids, 0), axis=1, keepdims=True)
+        w = jnp.sum(jnp.where(at, nets, 0), axis=1, keepdims=True)
+        mc, sel = _argmin_first(cnt2, lane)
+        hot = (lane == sel) & active
+        return (
+            i + active.astype(jnp.int32),
+            jnp.where(hot, uid, ids2),
+            jnp.where(hot, sat_add(mc, w), cnt2),
+            jnp.where(hot, mc, err2),
+        )
+
+    _, ids2, cnt2, err2 = jax.lax.while_loop(
+        ins_cond, ins_step, (start, ids2, cnt2, err2))
+
+    if variant != VARIANT_LAZY:
+        def sp_cond(carry):
+            rem, _, _, _, maxe = carry
+            return _any((rem > 0) & (maxe > 0))
+
+        def sp_step(carry):
+            rem, cnt2, err2, sel, maxe = carry
+            active = (rem > 0) & (maxe > 0)
+            d = jnp.where(active, jnp.minimum(rem, maxe), 0)
+            hot = (lane == sel) & active
+            nd = jnp.negative(d)
+            cnt2 = jnp.where(hot, sat_add(cnt2, nd), cnt2)
+            err2 = jnp.where(hot, sat_add(err2, nd), err2)
+            maxe, sel = _argmax_first(err2, lane)
+            return sat_add(rem, nd), cnt2, err2, sel, maxe
+
+        maxe0, sel0 = _argmax_first(err2, lane)
+        _, cnt2, err2, _, _ = jax.lax.while_loop(
+            sp_cond, sp_step, (w_del, cnt2, err2, sel0, maxe0))
+    return ids2, cnt2, err2
+
 
 def _fused_kernel_tile(scalars_ref, uids_ref, nets_ref, delta_ref, ids_ref,
                        counts_ref, errors_ref, ids_out, counts_out,
@@ -78,47 +303,35 @@ def _fused_kernel_tile(scalars_ref, uids_ref, nets_ref, delta_ref, ids_ref,
     """One grid step: the whole update pipeline for a tile of bank rows.
 
     The XLA prep (``bank.phase1_dense_prep``: sorts, searchsorted
-    matching, grouping — none of which lower in Mosaic) hands this
-    kernel per-row tensors; everything per-*cell* happens here on the
-    VMEM-resident tile in one launch:
+    matching, grouping) hands this kernel per-row tensors; every row's
+    stream row is laid out [units | non-units | consumed-by-fill | rest]
+    from lane 0. Everything per *cell* happens here on the VMEM-resident
+    tile in one launch:
 
       phase 1    saturating scatter of the monitored delta;
-      phase 1.5  bulk empty fill (vmapped over tile rows);
+      phase 1.5  bulk empty fill;
       phase 1.75 unit-weight water-fill;
-      phase 2    the banked residual tournament, every tile row in
-                 lockstep (shared verbatim with the pure-JAX engine).
+      phase 2    the lockstep residual tournament and deletion spread.
 
-    Row independence makes tiling exact: each row's result never reads
-    another row, and the lockstep loops' extra trips (max over the tile
-    instead of the whole bank) are frozen no-ops for finished rows — so
-    any row_tile gives bit-identical banks.
+    The arithmetic is the engine's (``phases`` and
+    ``bank.residual_phase_banked``) step for step, so results are
+    bit-identical to ``bank.update_rows``. Row independence makes tiling
+    exact: each row's result never reads another row, and the lockstep
+    loops' extra trips are frozen no-ops for finished rows.
     """
-    from repro.sketch.bank import residual_phase_banked
-
-    # scalars = (4, RT) rows [i0, mu, nnu, w_del] for this tile's rows
-    i0 = scalars_ref[0]
-    mu = scalars_ref[1]
-    nnu = scalars_ref[2]
-    w_del = scalars_ref[3]
+    # scalars = (RT, 4) columns [i0, mu, nnu, w_del] for this tile's rows
+    sc = scalars_ref[...]
+    i0, mu, nnu, w_del = (sc[:, c:c + 1] for c in range(4))
     uids = uids_ref[...]
     nets = nets_ref[...]
-    RT, B = uids.shape
-    flat_u = uids.reshape(-1)
-    flat_n = nets.reshape(-1)
-    uoff = jnp.arange(RT, dtype=jnp.int32) * B
-
     ids = ids_ref[...]
     counts = sat_add(counts_ref[...], delta_ref[...])
     errors = errors_ref[...]
-    ids, counts, errors, _ = jax.vmap(
-        fill_empty_slots, in_axes=(0, 0, 0, None, None, 0, 0))(
-        ids, counts, errors, flat_u, flat_n, i0, uoff + mu + nnu)
-    ids, counts, errors = jax.vmap(
-        waterfill_unit_inserts, in_axes=(0, 0, 0, None, 0, 0))(
-        ids, counts, errors, flat_u, mu, uoff)
-    ids, counts, errors = residual_phase_banked(
-        ids, counts, errors, flat_u, flat_n, uoff, mu, mu + nnu, w_del,
-        variant)
+    ids, counts, errors = _fill_tile(ids, counts, errors, uids, nets, i0,
+                                     mu + nnu)
+    ids, counts, errors = _waterfill_tile(ids, counts, errors, uids, mu)
+    ids, counts, errors = _residual_tile(ids, counts, errors, uids, nets,
+                                         mu, mu + nnu, w_del, variant)
     ids_out[...] = ids
     counts_out[...] = counts
     errors_out[...] = errors
@@ -126,19 +339,27 @@ def _fused_kernel_tile(scalars_ref, uids_ref, nets_ref, delta_ref, ids_ref,
 
 def choose_row_tile(num_rows: int, k_pad: int, block: int,
                     budget_bytes: int) -> int:
-    """Largest divisor of ``num_rows`` whose tile fits the VMEM budget.
+    """Largest row tile that divides ``num_rows``, fits the VMEM budget
+    and meets the TPU tiling rule (a multiple of 8, or all rows).
 
-    Per grid step one slot holds the state tile (ids/counts/errors,
-    aliased in/out: 3 x RT x K_pad), the delta tile (RT x K_pad) and the
-    grouped stream tile (uids + nets: 2 x RT x B), all int32. The budget
-    is half of VMEM (repro.platform.vmem_budget_bytes) so the pipeline
-    can keep two slots resident — the double-buffer in DESIGN.md §14.
+    Per grid step, counted in int32 words per row: the in/out state and
+    delta tiles double-buffered by the pipeline (14 x K), the stream tile
+    (uids + nets, double-buffered: 4 x B) and the body's live
+    temporaries (about 12 x K + 6 x B). The budget is half of VMEM
+    (repro.platform.vmem_budget_bytes). ``num_rows`` must be a multiple
+    of 8 unless it fits in one tile; ``sketch_update_kernel_fused`` pads
+    rows to make it so.
     """
-    bytes_per_row = 4 * (4 * k_pad + 2 * block)
-    rt = max(1, min(num_rows, budget_bytes // max(bytes_per_row, 1)))
-    while num_rows % rt:
-        rt -= 1
-    return rt
+    bytes_per_row = 4 * (26 * k_pad + 10 * block)
+    fit = max(1, budget_bytes // bytes_per_row)
+    if num_rows <= fit:
+        return num_rows
+    rt = (min(fit, num_rows) // 8) * 8
+    while rt >= 8:
+        if num_rows % rt == 0:
+            return rt
+        rt -= 8
+    return 8
 
 
 def sketch_update_kernel_fused(
@@ -154,7 +375,7 @@ def sketch_update_kernel_fused(
     w_del: jax.Array,    # (R,) int32 summed unmonitored deletions per row
     *,
     variant: int = 2,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     row_tile: int | None = None,
 ):
     """ONE ``pallas_call`` for the whole bank update: grid over row
@@ -165,26 +386,43 @@ def sketch_update_kernel_fused(
     instead of two, and the grid pipeline streams the next tile's
     operands into VMEM while the current tile updates (Mosaic's
     ``emit_pipeline`` two-slot copy machinery — see DESIGN.md §14).
-    ``row_tile`` must divide R; None picks the largest tile fitting the
-    platform VMEM budget (``choose_row_tile``).
+    ``row_tile`` must divide R and be a multiple of 8 or R itself; None
+    picks the largest such tile fitting the platform VMEM budget
+    (``choose_row_tile``). A bank of more than one tile whose R is not a
+    multiple of 8 gets inert BLOCKED rows appended for the launch.
     """
     assert ids.ndim == 2 and ids.shape[1] % LANES == 0, ids.shape
     R, K = ids.shape
     B = h_uids.shape[1]
+    scalars = jnp.stack([i0, mu, nnu, w_del], axis=1).astype(jnp.int32)
     if row_tile is None:
         from repro.platform import vmem_budget_bytes
 
-        row_tile = choose_row_tile(R, K, B, vmem_budget_bytes())
-    assert R % row_tile == 0, (R, row_tile)
-    grid = (R // row_tile,)
-    out_shape = [jax.ShapeDtypeStruct((R, K), jnp.int32)] * 3
+        row_tile = choose_row_tile(-(-R // 8) * 8, K, B,
+                                   vmem_budget_bytes())
+        if row_tile >= R:
+            row_tile = R
+    pad = (-R) % row_tile
+    if pad:
+        # inert rows: BLOCKED slots, an all-zero stream, zero scalars
+        rows = lambda x, v: jnp.pad(x, ((0, pad), (0, 0)), constant_values=v)
+        ids, counts, errors = (rows(ids, -2), rows(counts, _INT_MAX),
+                               rows(errors, 0))
+        delta, h_uids, h_net, scalars = (rows(delta, 0), rows(h_uids, 0),
+                                         rows(h_net, 0), rows(scalars, 0))
+    Rp = R + pad
+    interpret = resolve_interpret(interpret)
+    if not interpret and row_tile != Rp and row_tile % 8:
+        raise ValueError(
+            f"row_tile={row_tile} breaks the TPU tiling rule: a tile of "
+            f"rows is a multiple of 8 or all {Rp} rows")
+    grid = (Rp // row_tile,)
+    out_shape = [jax.ShapeDtypeStruct((Rp, K), jnp.int32)] * 3
     kern = functools.partial(_fused_kernel_tile, variant=variant)
     state_spec = pl.BlockSpec((row_tile, K), lambda i: (i, 0))
     stream_spec = pl.BlockSpec((row_tile, B), lambda i: (i, 0))
-    scalar_spec = pl.BlockSpec((4, row_tile), lambda i: (0, i))
-    scalars = jnp.stack([i0.astype(jnp.int32), mu.astype(jnp.int32),
-                         nnu.astype(jnp.int32), w_del.astype(jnp.int32)])
-    return pl.pallas_call(
+    scalar_spec = pl.BlockSpec((row_tile, 4), lambda i: (i, 0))
+    out = pl.pallas_call(
         kern,
         grid=grid,
         out_shape=out_shape,
@@ -194,6 +432,7 @@ def sketch_update_kernel_fused(
         input_output_aliases={4: 0, 5: 1, 6: 2},  # state updated in place
         interpret=interpret,
     )(scalars, h_uids, h_net, delta, ids, counts, errors)
+    return [x[:R] for x in out] if pad else out
 
 
 # ---------------------------------------------------------------------------
@@ -228,7 +467,7 @@ def sketch_residual_kernel(
     w_del: jax.Array,    # () int32 summed unmonitored deletion weight
     *,
     variant: int = 2,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     assert ids.ndim == 2 and ids.shape[1] == LANES, ids.shape
     B = r_uids.shape[0]
@@ -247,7 +486,7 @@ def sketch_residual_kernel(
                   state_spec, state_spec, state_spec],
         out_specs=[state_spec] * 3,
         input_output_aliases={3: 0, 4: 1, 5: 2},  # state updated in place
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(scalars, r_uids, r_net, ids, counts, errors)
 
 
@@ -287,7 +526,7 @@ def sketch_residual_kernel_banked(
     w_del: jax.Array,    # (R,) int32 summed unmonitored deletions per row
     *,
     variant: int = 2,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     assert ids.ndim == 2 and ids.shape[1] % LANES == 0, ids.shape
     R, K = ids.shape
@@ -306,7 +545,7 @@ def sketch_residual_kernel_banked(
                   state_spec, state_spec, state_spec],
         out_specs=[state_spec] * 3,
         input_output_aliases={3: 0, 4: 1, 5: 2},  # state updated in place
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(scalars, h_uids, h_net, ids, counts, errors)
 
 
@@ -401,7 +640,7 @@ def sketch_update_kernel_serial(
     weights: jax.Array,  # (B,) int32 signed
     *,
     variant: int = 2,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     assert ids.ndim == 2 and ids.shape[1] == LANES, ids.shape
     B = items.shape[0]
@@ -416,5 +655,5 @@ def sketch_update_kernel_serial(
         in_specs=[upd_spec, upd_spec, state_spec, state_spec, state_spec],
         out_specs=[state_spec] * 3,
         input_output_aliases={2: 0, 3: 1, 4: 2},  # state updated in place
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(items, weights, ids, counts, errors)

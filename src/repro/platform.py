@@ -11,10 +11,17 @@ point defaults to ``interpret=None`` and resolves it here:
     interpret = bool   -> honored as given (tests pin interpret=True to
                           run kernel paths on CPU CI)
 
-The module also selects the roofline hardware preset
-(``repro.roofline.model.HW_PRESETS``) matching the detected backend, so
-peak-fraction numbers in BENCH_kernels.json are computed against the
-hardware that actually ran the bench rather than a hardcoded TPU v5e.
+On a TPU an explicit ``interpret=True`` is an error: the chip runs
+compiled kernels only, so no kernel path there can silently fall back to
+the interpreter.
+
+The module also keys the roofline hardware presets
+(``repro.roofline.model.HW_PRESETS``) by the ``device_kind`` JAX reports,
+and raises for a device it has no preset for, so a peak fraction is only
+ever computed against the hardware that ran the bench.
+
+``enable_compile_cache`` places JAX's persistent compilation cache for
+entry-point scripts; importing the library never touches it.
 
 Environment mutation (``set_platform`` / ``set_host_device_count``)
 must happen before JAX initializes its backends — call these at process
@@ -35,6 +42,9 @@ __all__ = [
     "has_accelerator",
     "resolve_interpret",
     "donate_state_buffers",
+    "checkout_root",
+    "enable_compile_cache",
+    "device_kind",
     "hw_config",
     "vmem_budget_bytes",
     "lanes_for",
@@ -94,11 +104,16 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
 
     ``None`` (the default on every kernel entry point) means "compiled
     kernel when an accelerator is attached, interpret emulation
-    otherwise". An explicit bool is honored unchanged so CPU CI can pin
-    kernel paths with ``interpret=True``.
+    otherwise". An explicit bool is honored unchanged off the TPU so CPU
+    CI can pin kernel paths with ``interpret=True``; on a TPU that pin
+    raises instead of running the interpreter on the chip's host.
     """
     if interpret is None:
         return not has_accelerator()
+    if interpret and default_backend() == "tpu":
+        raise ValueError(
+            "interpret=True on a TPU: Pallas kernels run compiled on the "
+            "chip; leave interpret=None")
     return bool(interpret)
 
 
@@ -107,27 +122,59 @@ def donate_state_buffers() -> bool:
 
     Donation lets XLA reuse the incoming bank buffer for the output —
     the right call on accelerators where the bank is large and HBM
-    copies cost real bandwidth. On CPU it stays off: the CPU runtime
-    often ignores the donation (emitting a warning per compile) and the
-    session keeps a host reference to the pre-ingest state for
-    fault-replay, which donation would invalidate (DESIGN.md §14).
+    copies cost real bandwidth. On CPU it stays off by policy, not
+    because the runtime ignores it: JAX's CPU backend honors donation
+    (a donated array reads back ``is_deleted()``). Tests that must
+    exercise the donated path without a chip force this to True
+    (tests/test_sketch_service.py), so a use-after-donate shows up on
+    the CPU first.
     """
     return has_accelerator()
 
 
-def hw_config(name: Optional[str] = None):
-    """The roofline HWConfig for ``name``, or for the detected backend.
+def checkout_root() -> str:
+    """The repository checkout this package runs from (``src/..``)."""
+    return os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
 
-    Detected backends map onto presets as cpu->'cpu', gpu->'gpu_a100',
-    tpu->'tpu_v5e'; unknown names raise with the list of presets.
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Called by entry-point scripts (``chip_smoke.py``,
+    ``benchmarks/run.py``), never on import. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
+    sets no other directory. Otherwise the cache goes to the fixed
+    ``<checkout>/.jax_cache``: the path is part of the cache key, so a
+    temporary or per-process directory would never hit.
     """
-    from repro.roofline.model import HW_PRESETS, hw_for
+    import jax
 
-    if name is None:
-        name = {"cpu": "cpu", "gpu": "gpu_a100", "tpu": "tpu_v5e"}.get(
-            default_backend(), "cpu")
-    assert HW_PRESETS  # keep the registry import load-bearing
-    return hw_for(name)
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    path = os.path.join(checkout_root(), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def device_kind() -> str:
+    """``device_kind`` of the first JAX device (e.g. 'TPU v5 lite')."""
+    import jax
+
+    return jax.devices()[0].device_kind
+
+
+def hw_config(kind: Optional[str] = None):
+    """The roofline HWConfig for ``kind``, or for the attached device.
+
+    Presets are keyed by the ``device_kind`` JAX reports. A kind with no
+    preset — the CPU among them — raises: a peak fraction against some
+    other device's peaks is a wrong number, not a default.
+    """
+    from repro.roofline.model import hw_for
+
+    return hw_for(device_kind() if kind is None else kind)
 
 
 def vmem_budget_bytes(platform: Optional[str] = None) -> int:

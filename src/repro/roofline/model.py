@@ -44,39 +44,39 @@ class HWConfig:
         return self.int_flops or self.peak_flops
 
 
-# Registry of hardware presets, selected by ``repro.platform.hw_config``
-# from the detected JAX backend so peak-fraction numbers are computed
-# against the hardware that ran the bench (the old behavior silently
-# rooflined CPU interpret-mode runs against TPU v5e HBM).
-#   cpu:      one modern server core's share (benches are single-threaded
-#             per-cell): ~50 GFLOP/s, ~30 GB/s DRAM stream bandwidth.
-#   gpu_a100: A100-80GB SXM: 312 TFLOP/s bf16, 2.0 TB/s HBM2e, 600 GB/s
-#             NVLink, 19.5 TFLOP/s int32.
-#   tpu_v5e:  the original constants (197 TFLOP/s bf16, 819 GB/s HBM,
-#             50 GB/s ICI link); int ~ one VPU lane-op per cycle.
+# Published peaks per chip, keyed by the ``device_kind`` JAX reports
+# (``repro.platform.hw_config`` looks the attached device up here). A
+# device that is not in the table is an error, not a default: a peak
+# fraction against another device's peaks is a wrong number.
+#   TPU v5 lite (v5e): Google Cloud documentation, "TPU v5e" — 197
+#             TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI;
+#             int ~ one VPU lane-op per cycle (estimate, not published).
+#   NVIDIA A100-SXM4-80GB: NVIDIA A100 datasheet — 312 TFLOP/s bf16,
+#             2.0 TB/s HBM2e, 600 GB/s NVLink, 19.5 TOP/s int32.
 HW_PRESETS: Dict[str, HWConfig] = {
-    "cpu": HWConfig(name="cpu", peak_flops=5e10, hbm_bw=3e10,
-                    link_bw=1e10, hbm_bytes=64e9, int_flops=5e10),
-    "gpu_a100": HWConfig(name="gpu_a100", peak_flops=312e12, hbm_bw=2.0e12,
-                         link_bw=600e9, hbm_bytes=80e9, int_flops=19.5e12),
-    "tpu_v5e": HWConfig(name="tpu_v5e", peak_flops=197e12, hbm_bw=819e9,
-                        link_bw=50e9, hbm_bytes=16e9, int_flops=4e12),
+    "TPU v5 lite": HWConfig(name="tpu_v5e", peak_flops=197e12,
+                            hbm_bw=819e9, link_bw=50e9, hbm_bytes=16e9,
+                            int_flops=4e12),
+    "NVIDIA A100-SXM4-80GB": HWConfig(name="gpu_a100", peak_flops=312e12,
+                                      hbm_bw=2.0e12, link_bw=600e9,
+                                      hbm_bytes=80e9, int_flops=19.5e12),
 }
 
 
-def hw_for(name: str) -> HWConfig:
+def hw_for(kind: str) -> HWConfig:
+    """The preset for one ``device_kind``; raises for any other kind."""
     try:
-        return HW_PRESETS[name]
+        return HW_PRESETS[kind]
     except KeyError:
         raise KeyError(
-            f"unknown hardware preset {name!r}; "
-            f"available: {sorted(HW_PRESETS)}") from None
+            f"no hardware preset for device_kind {kind!r}; "
+            f"known kinds: {sorted(HW_PRESETS)}") from None
 
 
 # Default for the transformer-side roofline terms below (the launch
 # configs target v5e pods); sketch benches pass an explicit HWConfig
 # resolved by repro.platform instead of this global.
-HW = HW_PRESETS["tpu_v5e"]
+HW = HW_PRESETS["TPU v5 lite"]
 
 
 @dataclasses.dataclass
@@ -372,7 +372,7 @@ def sketch_ingest_cost(
 
 
 def sketch_roofline(cost: Dict[str, float], wall_s: float,
-                    hw: Optional[HWConfig] = None) -> Dict[str, float]:
+                    hw: Optional[HWConfig]) -> Dict[str, object]:
     """Roofline columns for one bench cell given its analytic cost.
 
     achieved_bytes_per_s — analytic bytes moved / measured wall time;
@@ -380,15 +380,23 @@ def sketch_roofline(cost: Dict[str, float], wall_s: float,
                            (the sketch ingest is memory-bound at its
                            ~1.6 op/byte intensity on every preset);
     arith_intensity      — analytic flops / analytic bytes (op/byte).
+
+    ``hw=None`` (no preset: a CPU run) leaves the device columns
+    ``peak_fraction``/``bound_s``/``bound`` as "not measured" instead
+    of reading a CPU time against some chip's peaks.
     """
-    hw = hw or HW
     achieved = cost["bytes"] / wall_s if wall_s > 0 else 0.0
-    memory_s = cost["bytes"] / hw.hbm_bw
-    compute_s = cost["flops"] / hw.peak_int_ops
-    return {
+    out: Dict[str, object] = {
         "achieved_bytes_per_s": achieved,
-        "peak_fraction": achieved / hw.hbm_bw,
         "arith_intensity": cost["flops"] / cost["bytes"] if cost["bytes"] else 0.0,
-        "bound_s": max(memory_s, compute_s),
-        "bound": "memory" if memory_s >= compute_s else "compute",
+        "peak_fraction": "not measured",
+        "bound_s": "not measured",
+        "bound": "not measured",
     }
+    if hw is not None:
+        memory_s = cost["bytes"] / hw.hbm_bw
+        compute_s = cost["flops"] / hw.peak_int_ops
+        out.update(peak_fraction=achieved / hw.hbm_bw,
+                   bound_s=max(memory_s, compute_s),
+                   bound="memory" if memory_s >= compute_s else "compute")
+    return out

@@ -54,6 +54,10 @@ def markdown_table(recs: List[Dict]) -> str:
     return hdr + "\n".join(rows) + "\n"
 
 
+def _pct(x) -> str:
+    return f"{x * 100:.1f}%" if isinstance(x, (int, float)) else str(x)
+
+
 def sketch_kernel_table(json_path) -> str:
     """BENCH_kernels.json sketch_update rows -> roofline markdown.
 
@@ -76,7 +80,7 @@ def sketch_kernel_table(json_path) -> str:
             f"| {r['dist']} | {r['state']} | {r['k']} | {r['block']} "
             f"| {r['fused_ms']:.2f} | {r['fused_speedup']:.2f}x "
             f"| {r['achieved_bytes_per_s']/1e9:.2f} "
-            f"| {r['peak_fraction']*100:.1f}% "
+            f"| {_pct(r['peak_fraction'])} "
             f"| {r['arith_intensity']:.3f} "
             f"| {'yes' if r['bit_identical'] else '**NO**'} |"
         )

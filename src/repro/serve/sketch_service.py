@@ -57,6 +57,10 @@ from repro.sketch import tenant as tn
 from repro.sketch.session import BlockFeeder, StreamSession
 
 
+# smallest padded key count of a tick's batched point query
+_MIN_QUERY_PAD = 128
+
+
 class QueryTicket:
     """One pending point-query: resolves at the next ``tick``.
 
@@ -167,9 +171,12 @@ class SketchService:
         self._tick = 0
         # optional parity hook: a list here records every (items,
         # weights) block fed, so a bench can replay the exact block
-        # sequence through tenant.reference_row_update
+        # sequence through tenant.reference_row_update; with it set,
+        # ``trace_admits`` records (len(trace_blocks), tenant) at each
+        # re-admission, whose merge reorders the tenant's slots
         self.trace_blocks: Optional[List[Tuple[np.ndarray, np.ndarray]]] \
             = None
+        self.trace_admits: List[Tuple[int, int]] = []
         self.stats = {"updates": 0, "queries": 0, "ticks": 0, "blocks": 0,
                       "spills": 0, "admits": 0}
 
@@ -302,10 +309,14 @@ class SketchService:
                 self.feeder.feed(ci, cw)
                 self.stats["blocks"] += 1
             self.feeder.flush()  # the tick's consistency barrier
-        # 3) all point queries in one owner-row gather
+        # 3) all point queries in one owner-row gather, the key count
+        # padded to a power of two so a day of ticks compiles the
+        # gather a handful of times, not once per distinct count
         if self._tickets:
             all_keys = np.concatenate(
                 [self._pack(t.tenant, t.items) for t in self._tickets])
+            n_pad = max(_MIN_QUERY_PAD, 1 << (len(all_keys) - 1).bit_length())
+            all_keys = np.pad(all_keys, (0, n_pad - len(all_keys)))
             est = np.asarray(api.query_many(
                 self.spec, self.session.state,
                 jnp.asarray(all_keys.astype(np.int32))))
@@ -376,6 +387,8 @@ class SketchService:
         self.stats["spills"] += 1
 
     def _admit(self, tenant: int) -> None:
+        if self.trace_blocks is not None:
+            self.trace_admits.append((len(self.trace_blocks), tenant))
         bank = tn.admit_spill(self.session.state.bank,
                               self._spilled.pop(tenant))
         self.session.state = tn.TenantBank(bank=bank)
@@ -383,6 +396,15 @@ class SketchService:
         self.stats["admits"] += 1
 
     # -- synchronous conveniences ------------------------------------------
+
+    def tenant_snapshot(self, tenant: int) -> Dict[str, Any]:
+        """A tenant's counters in the spill format (``tenant.spill_rows``)
+        as they stand, whether resident or spilled; evicts nothing."""
+        tenant = self._check_tenant(tenant)
+        if tenant in self._spilled:
+            return dict(self._spilled[tenant])
+        return tn.spill_rows(self.session.state.bank, tenant,
+                             self.spec.shards or 1, self.item_bits)
 
     def _settle(self, tenant: Optional[int] = None) -> None:
         if self._pending or self._tickets:

@@ -558,7 +558,8 @@ def update_rows(bank: SketchState, row_items: jax.Array,
 # ---------------------------------------------------------------------------
 
 def _fused_partition(bank: SketchState, items: jax.Array, weights: jax.Array,
-                     router: HashShardRouter, variant: int) -> SketchState:
+                     router: HashShardRouter, variant: int,
+                     owner_of=None) -> SketchState:
     """Fused single-launch partition ingest: global phase 1, banked phase 2.
 
     The single-sketch two-phase pipeline (blocks._phase1) run once on
@@ -582,8 +583,12 @@ def _fused_partition(bank: SketchState, items: jax.Array, weights: jax.Array,
     multiset in the same order) — pinned against
     ``sharded.update_block_serial_reference`` by tests and
     BENCH_sharded.json.
+
+    ``owner_of`` overrides ``router.owner_of`` with a map onto the rows
+    of ``bank`` (``_fused_touched`` passes a bank of selected rows).
     """
-    S = router.num_rows
+    S = bank.ids.shape[0]
+    owner_of = owner_of or router.owner_of
     k = bank.ids.shape[1]
     items = items.astype(jnp.int32)
     weights = weights.astype(jnp.int32)
@@ -609,7 +614,7 @@ def _fused_partition(bank: SketchState, items: jax.Array, weights: jax.Array,
     head, net = segment_nets(uids[None, :], wts[None, :])
     head, net = head[0], net[0]  # per-unique net, valid at head positions
     valid = head & (uids >= 0) & (net != 0)
-    owner = router.owner_of(uids)  # read at head positions only
+    owner = owner_of(uids)  # read at head positions only
 
     # -- 2. monitored matching, all rows at once ---------------------------
     # searchsorted returns the FIRST occurrence = the segment head; the
@@ -715,6 +720,69 @@ def _fused_partition(bank: SketchState, items: jax.Array, weights: jax.Array,
     return SketchState(ids1, cnt1, err1)
 
 
+def touched_chunk_rows(block: int, k: int) -> int:
+    """Rows per chunk of the touched-rows ingest (``_fused_touched``).
+
+    The smallest power of two, and at least one 8-row sublane tile, whose
+    rows of ``k`` counters hold ``block * log2(block)`` slots: one pass
+    over a chunk's counters (the monitored match, a residual-loop trip)
+    then costs about what the block's own sorts do, so neither the
+    per-chunk sorts nor the rows' lockstep work dominates.
+    """
+    target = block * max(1, block.bit_length() - 1)
+    rows = -(-target // k)
+    return max(8, 1 << (rows - 1).bit_length())
+
+
+def _fused_touched(bank: SketchState, items: jax.Array, weights: jax.Array,
+                   router: TenantRouter, variant: int) -> SketchState:
+    """``_fused_partition`` over the rows the block touches, a chunk at a time.
+
+    Every phase of the partition ingest costs O(R·k) per block — the
+    monitored match, the fill and water-fill and each residual-loop trip
+    pass over the whole bank — though a block of B items reaches at most
+    B rows. Here the rows holding a nonzero weight are listed in
+    ascending order and ingested ``touched_chunk_rows(B, k)`` at a time:
+    each chunk is gathered into a bank of its own, fed the block with the
+    weights of every other row set to zero, and scattered back. Rows are
+    independent and a row with no nonzero weight is left unchanged by an
+    ingest, so the result is bit-identical to the whole-bank ingest,
+    whatever number of rows the block reaches.
+
+    Needs a monotone owner: the owner's rank among a chunk's rows is then
+    non-decreasing in key order too, as ``_fused_partition``'s run
+    boundaries require. Items of other rows carry weight 0 and never
+    count, whatever rank they get; padding slots of the last chunk
+    (index R) read a clipped row and are dropped on the way back.
+    """
+    R, k = bank.ids.shape
+    B = items.shape[0]
+    cap = touched_chunk_rows(B, k)
+    items = items.astype(jnp.int32)
+    weights = weights.astype(jnp.int32)
+    owner = router.owner_of(items)
+    hit = jnp.zeros((R,), bool).at[
+        jnp.where((weights != 0) & (owner >= 0), owner, R)].set(
+            True, mode="drop")
+    size = -(-min(R, B) // cap) * cap
+    rows = jnp.nonzero(hit, size=size, fill_value=R)[0].astype(jnp.int32)
+    n_chunks = (hit.sum(dtype=jnp.int32) + cap - 1) // cap
+
+    def chunk(c, bank):
+        sel = jax.lax.dynamic_slice(rows, (c * cap,), (cap,))
+        mine = sel[jnp.clip(jnp.searchsorted(sel, owner), 0, cap - 1)] == owner
+        sub = jax.tree.map(lambda x: jnp.take(x, sel, axis=0, mode="clip"),
+                           bank)
+        out = _fused_partition(
+            sub, items, jnp.where(mine, weights, 0), router, variant,
+            owner_of=lambda key: jnp.searchsorted(
+                sel, router.owner_of(key)).astype(jnp.int32))
+        return jax.tree.map(
+            lambda x, y: x.at[sel].set(y, mode="drop"), bank, out)
+
+    return jax.lax.fori_loop(0, n_chunks, chunk, bank)
+
+
 @functools.partial(jax.jit, static_argnames=("router", "variant"))
 def update_block_fused(bank: SketchState, items: jax.Array,
                        weights: jax.Array, router: Router,
@@ -728,6 +796,9 @@ def update_block_fused(bank: SketchState, items: jax.Array,
     the row's own routed view.
     """
     if router.kind == "partition":
+        if getattr(router, "monotone_owner", False) and router.num_rows \
+                > touched_chunk_rows(items.shape[0], bank.ids.shape[1]):
+            return _fused_touched(bank, items, weights, router, variant)
         return _fused_partition(bank, items, weights, router, variant)
     row_items, row_weights = router.route_dense(items, weights)
     return _fused_dense(bank, row_items, row_weights, variant)

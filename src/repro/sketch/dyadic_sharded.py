@@ -142,11 +142,13 @@ def _update_block_bank(
     )
 
 
+@functools.partial(jax.jit, static_argnames=("variant", "mesh", "axes"))
 def _update_block_shard_map(
     state: DyadicShardedState,
     items: jax.Array,
     weights: jax.Array,
     variant: int,
+    mesh,
     axes,
 ) -> DyadicShardedState:
     """shard_map ingest: each mesh slice updates its own shards' rows.
@@ -156,14 +158,11 @@ def _update_block_shard_map(
     per-shard weight masking rides along as an (S, bits, B) routed
     weight tensor partitioned with the bank, so the update itself moves
     no bytes across devices: each device runs the engine's dense fused
-    core on its local (S_loc*bits, k) rows.
+    core on its local (S_loc*bits, k) rows. Jitted with the mesh static,
+    so a stream of blocks compiles once.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from repro.parallel import sharding as psh
-
-    mesh = psh.current_mesh()
     S, bits, k = state.bank.ids.shape
     B = items.shape[0]
     router = ShardLevelRouter(bits, S)
@@ -182,12 +181,12 @@ def _update_block_shard_map(
 
     spec3 = SketchState(P(axes, None, None), P(axes, None, None),
                         P(axes, None, None))
-    fn = shard_map(
+    fn = jax.shard_map(
         local_update,
         mesh=mesh,
         in_specs=(spec3, P(None, None), P(axes, None, None)),
         out_specs=spec3,
-        check_rep=False,
+        check_vma=False,
     )
     return DyadicShardedState(
         bank=fn(state.bank, nodes, w_routed),
@@ -227,7 +226,10 @@ def update_block(
                 "logical axes divide num_shards "
                 "(repro.parallel.sharding.use_mesh)")
     if path == "shard_map":
-        return _update_block_shard_map(state, items, weights, variant, axes)
+        from repro.parallel import sharding as psh
+
+        return _update_block_shard_map(state, items, weights, variant,
+                                       psh.current_mesh(), axes)
     if path != "bank":
         raise ValueError(f"unknown path {path!r}")
     return _update_block_bank(state, items, weights, variant)

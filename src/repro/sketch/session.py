@@ -719,6 +719,13 @@ class StreamSession:
                 s += n
 
 
+@jax.jit
+def _ready_token(state):
+    """One element of ``state``: ready once the ingest that produced
+    ``state`` is, and still valid after ``state`` itself is donated."""
+    return jax.tree.leaves(state)[0].reshape(-1)[:1]
+
+
 class BlockFeeder:
     """Host-side two-slot feeder that keeps the compiled ingest saturated.
 
@@ -736,7 +743,10 @@ class BlockFeeder:
     At most ``depth`` ingests stay in flight (backpressure via
     ``block_until_ready`` on the oldest) so a fast host cannot queue
     unbounded device work. ``flush()`` dispatches the last staged block
-    and synchronizes.
+    and synchronizes. What the feeder waits on is a one-element read of
+    each ingest's output (``_ready_token``), not the output itself: a
+    donating session consumes that output in the next ingest, and a
+    donated buffer cannot be waited on.
 
     Blocks must be exactly session-block-sized and zero-weight padded
     (the ``StreamSession.ingest_block`` contract). Feeding through a
@@ -761,7 +771,7 @@ class BlockFeeder:
 
     def _dispatch(self, items, weights) -> None:
         self.session.ingest_block(items, weights)
-        self._inflight.append(self.session.state)
+        self._inflight.append(_ready_token(self.session.state))
         while len(self._inflight) > self.depth:
             jax.block_until_ready(self._inflight.popleft())
 

@@ -197,12 +197,15 @@ def _update_block_routed(
     return ShardedSketch(bank=bank)
 
 
+@functools.partial(
+    jax.jit, static_argnames=("variant", "universe_bits", "mesh", "axes"))
 def _update_block_shard_map(
     state: ShardedSketch,
     items: jax.Array,
     weights: jax.Array,
     variant: int,
     universe_bits: Optional[int],
+    mesh,
     axes,
 ) -> ShardedSketch:
     """shard_map ingest: each mesh slice updates its own S/n shard rows.
@@ -210,24 +213,21 @@ def _update_block_shard_map(
     Routing happens replicated (it is O(B log B) vector work on the raw
     block); the bank stays partitioned over the "shards" logical axis the
     whole time, so the update itself moves no bytes across devices.
+    Jitted with the mesh static, so a stream of blocks compiles once.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from repro.parallel import sharding as psh
-
-    mesh = psh.current_mesh()
     S = state.num_shards
     items_b, w_routed = route_block(items, weights, S, universe_bits)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda b, i, w: block_update_batched(b, i, w, variant,
                                              assume_sorted=True),
         mesh=mesh,
         in_specs=(SketchState(P(axes, None), P(axes, None), P(axes, None)),
                   P(axes, None), P(axes, None)),
         out_specs=SketchState(P(axes, None), P(axes, None), P(axes, None)),
-        check_rep=False,
+        check_vma=False,
     )
     return ShardedSketch(bank=fn(state.bank, items_b, w_routed))
 
@@ -283,8 +283,11 @@ def update_block(
                 f"{_axis_sizes(mesh, bound)}); got num_shards="
                 f"{state.num_shards}")
     if path == "shard_map":
+        from repro.parallel import sharding as psh
+
         return _update_block_shard_map(
-            state, items, weights, variant, universe_bits, axes)
+            state, items, weights, variant, universe_bits,
+            psh.current_mesh(), axes)
     if path == "block":
         return _update_block_fused(
             state, items, weights, variant, universe_bits)

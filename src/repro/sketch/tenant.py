@@ -199,12 +199,14 @@ def tenant_rows(tenant: int, num_shards: int) -> np.ndarray:
     return np.arange(t * num_shards, (t + 1) * num_shards)
 
 
+@jax.jit
 def extract_rows(bank: SketchState, rows) -> SketchState:
     """Row slice (n, k): the live content of those rows (spill payload)."""
     rows = jnp.asarray(rows, jnp.int32)
     return SketchState(bank.ids[rows], bank.counts[rows], bank.errors[rows])
 
 
+@jax.jit
 def clear_rows(bank: SketchState, rows) -> SketchState:
     """Reset rows to empty, preserving their BLOCKED capacity masks."""
     rows = jnp.asarray(rows, jnp.int32)
@@ -218,6 +220,7 @@ def clear_rows(bank: SketchState, rows) -> SketchState:
     )
 
 
+@jax.jit
 def admit_rows(bank: SketchState, rows, spilled: SketchState) -> SketchState:
     """Merge a spilled row bundle back into its rows; re-impose the rows'
     capacity masks.

@@ -55,7 +55,6 @@ def build_compressed_allreduce(mesh, k_frac: float = 0.01, axis: str = "data"):
     dims replicated. Use at smoke scale / benchmarks; the production path
     keeps GSPMD's dense all-reduce unless the collective term dominates.
     """
-    from jax.experimental.shard_map import shard_map
 
     def allreduce(grads, residuals):
         def body(g_tree, r_tree):
@@ -68,11 +67,11 @@ def build_compressed_allreduce(mesh, k_frac: float = 0.01, axis: str = "data"):
             return gs, rs
 
         specs_in = jax.tree.map(lambda _: P(), grads)
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(specs_in, specs_in),
             out_specs=(specs_in, specs_in),
-            check_rep=False,
+            check_vma=False,
         )(grads, residuals)
 
     return allreduce

@@ -35,12 +35,23 @@ def test_resolve_interpret_tristate():
     assert platform.resolve_interpret(False) is False
 
 
+def test_interpret_refused_on_tpu(monkeypatch):
+    # on a TPU no kernel entry point may resolve to the interpreter: the
+    # default compiles, and an explicit interpret=True pin raises
+    monkeypatch.setattr(platform, "default_backend", lambda: "tpu")
+    assert platform.resolve_interpret(None) is False
+    assert platform.resolve_interpret(False) is False
+    with pytest.raises(ValueError, match="interpret=True on a TPU"):
+        platform.resolve_interpret(True)
+
+
 def test_cpu_backend_resolution():
     if platform.default_backend() != "cpu":
         pytest.skip("accelerator attached")
     assert not platform.has_accelerator()
     assert platform.resolve_interpret(None) is True
-    # CPU cannot reuse donated buffers -> donation stays off
+    # donation stays off on the CPU by policy (it is forced on where a
+    # test must exercise the donated path)
     assert platform.donate_state_buffers() is False
 
 
@@ -70,20 +81,25 @@ def test_xla_host_device_flags():
 
 
 def test_hw_presets_registry():
-    assert set(HW_PRESETS) >= {"cpu", "gpu_a100", "tpu_v5e"}
+    # keyed by device_kind; no CPU preset, so a CPU run has no peaks
+    assert "TPU v5 lite" in HW_PRESETS and "cpu" not in HW_PRESETS
     for name, hw in HW_PRESETS.items():
         assert hw.peak_flops > 0 and hw.hbm_bw > 0, name
         assert hw.peak_int_ops > 0, name
-    with pytest.raises(KeyError, match="cpu"):
+    with pytest.raises(KeyError, match="device_kind"):
         hw_for("not_a_preset")
 
 
 def test_hw_config_matches_backend():
-    hw = platform.hw_config()
-    expected = {"cpu": "cpu", "gpu": "gpu_a100", "tpu": "tpu_v5e"}[
-        platform.default_backend()]
-    assert hw is HW_PRESETS[expected]
-    assert platform.hw_config("tpu_v5e") is HW_PRESETS["tpu_v5e"]
+    kind = jax.devices()[0].device_kind
+    assert platform.device_kind() == kind
+    if kind in HW_PRESETS:
+        assert platform.hw_config() is HW_PRESETS[kind]
+    else:
+        # an unknown device (the CPU among them) raises, never defaults
+        with pytest.raises(KeyError, match=repr(kind)):
+            platform.hw_config()
+    assert platform.hw_config("TPU v5 lite") is HW_PRESETS["TPU v5 lite"]
 
 
 def test_sketch_ingest_cost_shape():
@@ -99,13 +115,17 @@ def test_sketch_ingest_cost_shape():
 
 def test_sketch_roofline_columns():
     cost = sketch_ingest_cost(num_rows=1, k=4096, block=4096)
-    roof = sketch_roofline(cost, wall_s=1e-3, hw=HW_PRESETS["cpu"])
+    roof = sketch_roofline(cost, wall_s=1e-3, hw=HW_PRESETS["TPU v5 lite"])
     for col in ("achieved_bytes_per_s", "peak_fraction", "arith_intensity",
                 "bound_s", "bound"):
         assert col in roof, col
     assert roof["achieved_bytes_per_s"] == pytest.approx(cost["bytes"] / 1e-3)
     assert 0 < roof["arith_intensity"] < 10  # int32 scatter is memory-bound
     assert roof["bound"] in ("memory", "compute")
+    # no preset (a CPU run): the device columns are not measured
+    off = sketch_roofline(cost, wall_s=1e-3, hw=None)
+    assert off["peak_fraction"] == off["bound"] == "not measured"
+    assert off["achieved_bytes_per_s"] == roof["achieved_bytes_per_s"]
 
 
 # -- BlockFeeder: pipelined == sequential -------------------------------

@@ -240,3 +240,67 @@ def test_service_stats_and_blocks():
     svc.tick()
     assert svc.stats["blocks"] >= 4  # 100 keys / 32-wide blocks
     assert all(len(i) == 32 for i, _ in svc.trace_blocks)
+
+
+@pytest.fixture
+def donated(monkeypatch):
+    """Force the accelerator donation policy on the CPU.
+
+    The compiled-ingest cache keys on the donate flag but not on the
+    policy, so it is cleared on the way in and out: no donating entry
+    leaks into other tests.
+    """
+    from repro import platform
+
+    ses._ingest_fn_cached.cache_clear()
+    monkeypatch.setattr(platform, "donate_state_buffers", lambda: True)
+    yield
+    ses._ingest_fn_cached.cache_clear()
+
+
+def test_donated_service_matches_undonated_twin(donated):
+    """The default service path on a chip donates the bank into every
+    ingest. Drive it through multi-block ticks (feeder backpressure),
+    spill and re-admission, top-k subscriptions and save/load against
+    a twin that never donates: any use of a donated buffer raises here,
+    on the CPU, instead of on the chip."""
+    spec = _freq_spec(T=8, k_t=16)
+    svc = SketchService(spec, block=32, spill_after=2)
+    twin = SketchService(spec, block=32, spill_after=2, donate=False)
+    rng = np.random.default_rng(6)
+
+    def both(fn):
+        return fn(svc), fn(twin)
+
+    both(lambda s: s.subscribe_topk(0, 4))
+    before = svc.session.state.bank.ids
+    for step in range(6):
+        hot = rng.integers(0, 16, 150)         # ~5 blocks per tick
+        both(lambda s, hot=hot: s.submit(0, hot))
+        if step < 2:
+            for t in range(1, 8):
+                items = rng.integers(0, 16, 10)
+                both(lambda s, t=t, items=items: s.submit(t, items))
+        both(lambda s: s.tick())
+    assert before.is_deleted()                  # the bank was donated
+    assert svc.stats["spills"] >= 1 and svc.stats["blocks"] >= 20
+    probe = np.arange(16)
+    for t in range(8):                          # re-admits spilled rows
+        a, b = both(lambda s, t=t: s.query(t, probe).result())
+        np.testing.assert_array_equal(a, b)
+    assert svc.stats["admits"] >= 1
+    for x, y in zip(svc.topk_result(0), twin.topk_result(0)):
+        np.testing.assert_array_equal(x, y)
+
+    resumed = SketchService(spec, block=32, spill_after=2)
+    resumed.load(svc.save())
+    hot = rng.integers(0, 16, 100)
+    for s in (resumed, twin):
+        s.submit(3, [1, 1, 2])
+        s.submit(0, hot)
+        s.tick()
+    for t in range(8):
+        np.testing.assert_array_equal(resumed.query(t, probe).result(),
+                                      twin.query(t, probe).result())
+    for x, y in zip(resumed.topk(0, 4), twin.topk(0, 4)):
+        np.testing.assert_array_equal(x, y)

@@ -242,6 +242,41 @@ def test_fused_matches_serial_reference(variant_id, shards):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+@pytest.mark.parametrize("variant_id", [1, 2])
+@pytest.mark.parametrize("chunks", [1, 4])
+def test_touched_rows_path_matches_whole_bank(variant_id, chunks):
+    """A bank wider than one chunk of ``touched_chunk_rows``: blocks
+    reaching one chunk's worth of tenants, or four chunks' (the last
+    partial), equal the whole-bank ingest bit for bit, through
+    evictions and deletions."""
+    B, k = 256, 64
+    cap = bk.touched_chunk_rows(B, k)
+    T = 4 * cap + 8
+    spread = (chunks - 1) * cap + cap // 2 + 4
+    router = tn.router_for(T, BITS, 1)
+    bank = tn.init_tenants(k, num_tenants=T).bank
+    ref = bank
+    rng = np.random.default_rng(chunks)
+    whole = jax.jit(bk._fused_partition, static_argnums=(3, 4))
+    heavy = rng.choice(T, 2, replace=False)
+    for _ in range(4):
+        # every chosen tenant gets an item; two fixed ones get the rest
+        others = rng.choice(np.setdiff1d(np.arange(T), heavy), spread - 2,
+                            replace=False)
+        tenants = np.concatenate([heavy, others])
+        t = np.concatenate([tenants, rng.choice(heavy, B - spread)])
+        items = rng.integers(0, 200, B)
+        w = rng.choice([-1, 1, 1, 2], B)
+        w[spread:][rng.random(B - spread) < 0.1] = 0
+        keys = jnp.asarray(tn.pack_keys(t, items, BITS).astype(np.int32))
+        w = jnp.asarray(w.astype(np.int32))
+        bank = bk.update_block_fused(bank, keys, w, router, variant_id)
+        ref = whole(ref, keys, w, router, variant_id)
+    assert int((np.asarray(ref.errors) > 0).sum()) > 0  # evictions ran
+    for a, b in zip(bank, ref):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 @given(seed=hyp_st.integers(0, 2**16), delete_frac=hyp_st.sampled_from(
     [0.0, 0.3, 0.7]))
 @settings(max_examples=15, deadline=None)
