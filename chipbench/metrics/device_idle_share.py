@@ -1,0 +1,8 @@
+"""The device: the share of the traced window in which no operation ran
+on it (1 - busy union / window), in percent."""
+
+
+def read(run):
+    if run.trace is None or run.trace.window_s <= 0:
+        return None
+    return (1.0 - run.trace.busy_s / run.trace.window_s) * 100.0
