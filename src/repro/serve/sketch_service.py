@@ -37,6 +37,20 @@ A tick is the service's consistency barrier: after ``tick()`` returns,
 every update submitted before it is visible to every query answered by
 it, exactly once (the feeder flush joins the device).
 
+Telemetry: each stage that has work runs inside a span — ``admit``,
+``ingest``, ``query``, ``subscriptions``, ``spill`` (every tick while
+spill is on: its scan for idle tenants is work) — and every point
+where the tick blocks on the device (the feeder's waits, the query and
+top-k reads, the spill reads) inside a nested ``wait`` span. A span is
+a ``jax.profiler.TraceAnnotation`` named ``sketch.<name>`` carrying
+``tick=<n>``, so it lands on a profiler trace's host plane, on the
+device's clock; it also adds its nanoseconds and a count to
+``stats['<name>_ns']`` and ``stats['<name>_n']``. ``wait_n`` is thus
+the tick's host syncs. ``stats['ingest_chunks']`` counts the trips of
+the ingest's chunk loop the fed blocks take (``bank.ingest_chunks``).
+Every value in ``stats`` is a flat int, so ``dict(svc.stats)`` is a
+snapshot.
+
 Crash/resume: ``save()`` bundles the session checkpoint WITH schedule
 (per-tenant window FIFOs ride the ``sched_batch_tenants`` tags), the
 spill store and the tick cursor; ``load`` of that bundle resumes
@@ -45,20 +59,51 @@ against an uninterrupted twin).
 """
 from __future__ import annotations
 
+import functools
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from repro.sketch import api
+from repro.sketch import bank as bk
 from repro.sketch import tenant as tn
 from repro.sketch.session import BlockFeeder, StreamSession
 
 
 # smallest padded key count of a tick's batched point query
 _MIN_QUERY_PAD = 128
+
+# the tick's spans (see the module docstring), each with its stats keys
+_SPANS = ("admit", "ingest", "query", "subscriptions", "spill", "wait")
+
+
+class _Span:
+    """One ``with`` block of the tick: a profiler annotation
+    ``sketch.<name>`` (metadata ``tick``), timed into ``stats``."""
+
+    __slots__ = ("_stats", "_keys", "_note", "_t0")
+
+    _KEYS = {n: (f"sketch.{n}", f"{n}_ns", f"{n}_n") for n in _SPANS}
+
+    def __init__(self, stats: Dict[str, int], name: str, tick: int):
+        self._stats = stats
+        self._keys = keys = self._KEYS[name]
+        self._note = jax.profiler.TraceAnnotation(keys[0], tick=tick)
+
+    def __enter__(self):
+        self._note.__enter__()
+        self._t0 = time.perf_counter_ns()
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter_ns() - self._t0
+        self._note.__exit__(*exc)
+        _, ns, n = self._keys
+        self._stats[ns] += dt
+        self._stats[n] += 1
 
 
 class QueryTicket:
@@ -154,7 +199,13 @@ class SketchService:
         self.spec = spec
         self.session = StreamSession(spec, block=block, window=window,
                                      donate=donate)
-        self.feeder = BlockFeeder(self.session, depth=depth)
+        self.stats = {"updates": 0, "queries": 0, "ticks": 0, "blocks": 0,
+                      "spills": 0, "admits": 0, "ingest_chunks": 0}
+        for name in _SPANS:
+            self.stats[f"{name}_ns"] = self.stats[f"{name}_n"] = 0
+        self._wait = functools.partial(self._span, "wait")
+        self.feeder = BlockFeeder(self.session, depth=depth,
+                                  wait=self._wait)
         self.spill_after = spill_after
         if spill_after is not None and not self._spillable():
             raise ValueError(
@@ -177,11 +228,17 @@ class SketchService:
         self.trace_blocks: Optional[List[Tuple[np.ndarray, np.ndarray]]] \
             = None
         self.trace_admits: List[Tuple[int, int]] = []
-        self.stats = {"updates": 0, "queries": 0, "ticks": 0, "blocks": 0,
-                      "spills": 0, "admits": 0}
+        # the router the engine ingests with, for the chunk count
+        self._router = (tn.router_for(self.num_tenants, self.item_bits,
+                                      spec.shards or 1)
+                        if isinstance(self.session.state, tn.TenantBank)
+                        else None)
 
     def _spillable(self) -> bool:
         return isinstance(self.session.state, tn.TenantBank)
+
+    def _span(self, name: str) -> _Span:
+        return _Span(self.stats, name, self._tick)
 
     @property
     def tick_count(self) -> int:
@@ -272,11 +329,53 @@ class SketchService:
         """One batched service step (see the module docstring's stages)."""
         # 1) exact re-admission before any of this tick's work
         touched = set(self._pending) | {t.tenant for t in self._tickets}
-        for t in sorted(touched & set(self._spilled)):
-            self._admit(t)
+        readmit = sorted(touched & set(self._spilled))
+        if readmit:
+            with self._span("admit"):
+                for t in readmit:
+                    self._admit(t)
         # 2) coalesce updates + due window expiries across tenants
+        if self._pending:
+            with self._span("ingest"):
+                self._ingest_pending()
+        # 3) all point queries in one owner-row gather, the key count
+        # padded to a power of two so a day of ticks compiles the
+        # gather a handful of times, not once per distinct count
+        if self._tickets:
+            with self._span("query"):
+                all_keys = np.concatenate(
+                    [self._pack(t.tenant, t.items) for t in self._tickets])
+                n_pad = max(_MIN_QUERY_PAD,
+                            1 << (len(all_keys) - 1).bit_length())
+                all_keys = np.pad(all_keys, (0, n_pad - len(all_keys)))
+                est = api.query_many(
+                    self.spec, self.session.state,
+                    jnp.asarray(all_keys.astype(np.int32)))
+                with self._wait():
+                    est = np.asarray(est)
+                now = time.perf_counter()
+                s = 0
+                for t in self._tickets:
+                    n = len(t.items)
+                    t._value = est[s:s + n]
+                    t.t_resolve = now
+                    s += n
+                self._tickets.clear()
+        # 4) due subscriptions, batched where the layout allows
+        if self._topk_subs or self._quant_subs:
+            with self._span("subscriptions"):
+                self._refresh_subscriptions()
+        # 5) evict cold tenants
+        if self.spill_after is not None:
+            with self._span("spill"):
+                self._spill_idle()
+        self._tick += 1
+        self.stats["ticks"] += 1
+
+    def _ingest_pending(self) -> None:
         frags_i: List[np.ndarray] = []
         frags_w: List[np.ndarray] = []
+        ends: List[int] = []   # end of each tenant's run in the stream
         for t in sorted(self._pending):
             parts = self._pending[t]
             ki = (np.concatenate([i for i, _ in parts])
@@ -285,56 +384,52 @@ class SketchService:
                   if len(parts) > 1 else parts[0][1])
             frags_i.append(ki)
             frags_w.append(kw)
+            n = len(ki)
             # the tick's batch ages on tenant t's OWN horizon; expiries
             # due now join the same coalesced stream (after the batch)
             for di, dw in self.session.schedule_batch(ki, kw, tenant=t):
                 frags_i.append(di)
                 frags_w.append(dw)
+                n += len(di)
+            ends.append((ends[-1] if ends else 0) + n)
             self._last_active[t] = self._tick
         self._pending.clear()
-        if frags_i:
-            items = (np.concatenate(frags_i) if len(frags_i) > 1
-                     else frags_i[0])
-            weights = (np.concatenate(frags_w) if len(frags_w) > 1
-                       else frags_w[0])
-            B = self.session.block
-            for s in range(0, len(items), B):
-                ci, cw = items[s:s + B], weights[s:s + B]
-                pad = B - len(ci)
-                if pad:
-                    ci = np.pad(ci, (0, pad))  # weight-0 tail = padding
-                    cw = np.pad(cw, (0, pad))
-                if self.trace_blocks is not None:
-                    self.trace_blocks.append((ci.copy(), cw.copy()))
-                self.feeder.feed(ci, cw)
-                self.stats["blocks"] += 1
-            self.feeder.flush()  # the tick's consistency barrier
-        # 3) all point queries in one owner-row gather, the key count
-        # padded to a power of two so a day of ticks compiles the
-        # gather a handful of times, not once per distinct count
-        if self._tickets:
-            all_keys = np.concatenate(
-                [self._pack(t.tenant, t.items) for t in self._tickets])
-            n_pad = max(_MIN_QUERY_PAD, 1 << (len(all_keys) - 1).bit_length())
-            all_keys = np.pad(all_keys, (0, n_pad - len(all_keys)))
-            est = np.asarray(api.query_many(
-                self.spec, self.session.state,
-                jnp.asarray(all_keys.astype(np.int32))))
-            now = time.perf_counter()
-            s = 0
-            for t in self._tickets:
-                n = len(t.items)
-                t._value = est[s:s + n]
-                t.t_resolve = now
-                s += n
-            self._tickets.clear()
-        # 4) due subscriptions, batched where the layout allows
-        self._refresh_subscriptions()
-        # 5) evict cold tenants
-        if self.spill_after is not None:
-            self._spill_idle()
-        self._tick += 1
-        self.stats["ticks"] += 1
+        if not frags_i:
+            return
+        items = (np.concatenate(frags_i) if len(frags_i) > 1
+                 else frags_i[0])
+        weights = (np.concatenate(frags_w) if len(frags_w) > 1
+                   else frags_w[0])
+        B = self.session.block
+        for s in range(0, len(items), B):
+            ci, cw = items[s:s + B], weights[s:s + B]
+            pad = B - len(ci)
+            if pad:
+                ci = np.pad(ci, (0, pad))  # weight-0 tail = padding
+                cw = np.pad(cw, (0, pad))
+            if self.trace_blocks is not None:
+                self.trace_blocks.append((ci.copy(), cw.copy()))
+            self.feeder.feed(ci, cw)
+            self.stats["blocks"] += 1
+        self.stats["ingest_chunks"] += self._chunks(np.asarray(ends), B)
+        self.feeder.flush()  # the tick's consistency barrier
+
+    def _chunks(self, ends: np.ndarray, B: int) -> int:
+        """Trips of the ingest's chunk loop over this tick's blocks.
+
+        One tenant owns one row on the chunked path, and ``ends`` closes
+        each tenant's run of the coalesced stream, so a block reaches the
+        runs that overlap it: those starting before its end, less those
+        ending at or before its start. A run counts as reached whatever
+        its weights."""
+        lo = np.arange(0, int(ends[-1]), B)   # each block's start
+        if self._router is None:
+            return len(lo)
+        starts = np.r_[0, ends[:-1]]
+        reached = (np.searchsorted(starts, lo + B, side="left")
+                   - np.searchsorted(ends, lo, side="right"))
+        k = self.session.state.bank.ids.shape[1]
+        return int(bk.ingest_chunks(self._router, B, k, reached).sum())
 
     def _refresh_subscriptions(self) -> None:
         due_topk = [t for t, s in self._topk_subs.items()
@@ -348,7 +443,10 @@ class SketchService:
                 items, vals = tn.topk_tenants(
                     self.session.state, jnp.asarray(due_topk, jnp.int32),
                     m, num_shards=shards, item_bits=self.item_bits)
-                items, vals = np.asarray(items), np.asarray(vals)
+                with self._wait():
+                    items = np.asarray(items)
+                with self._wait():
+                    vals = np.asarray(vals)
                 for i, t in enumerate(due_topk):
                     self._topk_subs[t]["value"] = (items[i], vals[i])
             else:
@@ -356,16 +454,22 @@ class SketchService:
                     sub = self._topk_subs[t]
                     ids, vals = api.tenant_topk(
                         self.spec, self.session.state, t, sub["m"])
-                    sub["value"] = (np.asarray(ids), np.asarray(vals))
+                    with self._wait():
+                        ids = np.asarray(ids)
+                    with self._wait():
+                        vals = np.asarray(vals)
+                    sub["value"] = (ids, vals)
             for t in due_topk:
                 self._topk_subs[t]["due"] = self._tick \
                     + self._topk_subs[t]["every"]
         for t, sub in self._quant_subs.items():
             if self._tick < sub["due"]:
                 continue
-            sub["value"] = np.asarray(tn.tenant_quantile_many(
+            qv = tn.tenant_quantile_many(
                 self.session.state, t, jnp.asarray(sub["qs"]),
-                self.item_bits))
+                self.item_bits)
+            with self._wait():
+                sub["value"] = np.asarray(qv)
             sub["due"] = self._tick + sub["every"]
 
     def _spill_idle(self) -> None:
@@ -381,7 +485,7 @@ class SketchService:
         shards = self.spec.shards or 1
         bank = self.session.state.bank
         self._spilled[tenant] = tn.spill_rows(
-            bank, tenant, shards, self.item_bits)
+            bank, tenant, shards, self.item_bits, wait=self._wait)
         rows = tn.tenant_rows(tenant, shards)
         self.session.state = tn.TenantBank(bank=tn.clear_rows(bank, rows))
         self.stats["spills"] += 1
@@ -448,7 +552,8 @@ class SketchService:
 
     def load(self, d: Dict[str, Any]) -> None:
         self.session.load(d["session"])
-        self.feeder = BlockFeeder(self.session, depth=self.feeder.depth)
+        self.feeder = BlockFeeder(self.session, depth=self.feeder.depth,
+                                  wait=self._wait)
         self._spilled = {int(t): dict(v) for t, v in d["spilled"].items()}
         self._last_active = {int(t): int(v)
                              for t, v in d["last_active"].items()}

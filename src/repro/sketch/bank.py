@@ -734,6 +734,28 @@ def touched_chunk_rows(block: int, k: int) -> int:
     return max(8, 1 << (rows - 1).bit_length())
 
 
+def takes_touched(router: Router, block: int, k: int) -> bool:
+    """Whether ``update_block_fused`` ingests a ``block``-item block into a
+    bank of rows ``k`` counters wide through ``_fused_touched``."""
+    return (router.kind == "partition"
+            and getattr(router, "monotone_owner", False)
+            and router.num_rows > touched_chunk_rows(block, k))
+
+
+def ingest_chunks(router: Router, block: int, k: int, rows_reached):
+    """Trips of the ingest's chunk loop, per block, on the host.
+
+    ``rows_reached`` (an int or an array, one per block) counts the rows
+    a block gives a nonzero weight. On the touched-rows path the loop
+    takes ``ceil(rows_reached / touched_chunk_rows(block, k))`` trips;
+    every other path is one pass over the whole bank.
+    """
+    rows_reached = np.asarray(rows_reached)
+    if not takes_touched(router, block, k):
+        return np.ones_like(rows_reached)
+    return -(-rows_reached // touched_chunk_rows(block, k))
+
+
 def _fused_touched(bank: SketchState, items: jax.Array, weights: jax.Array,
                    router: TenantRouter, variant: int) -> SketchState:
     """``_fused_partition`` over the rows the block touches, a chunk at a time.
@@ -795,10 +817,9 @@ def update_block_fused(bank: SketchState, items: jax.Array,
     bit-identical to updating each row with ``blocks.block_update`` on
     the row's own routed view.
     """
+    if takes_touched(router, items.shape[0], bank.ids.shape[1]):
+        return _fused_touched(bank, items, weights, router, variant)
     if router.kind == "partition":
-        if getattr(router, "monotone_owner", False) and router.num_rows \
-                > touched_chunk_rows(items.shape[0], bank.ids.shape[1]):
-            return _fused_touched(bank, items, weights, router, variant)
         return _fused_partition(bank, items, weights, router, variant)
     row_items, row_weights = router.route_dense(items, weights)
     return _fused_dense(bank, row_items, row_weights, variant)
@@ -962,6 +983,7 @@ __all__ = [
     "update_rows",
     "update_block_fused",
     "update_single",
+    "ingest_chunks",
     "query_rows",
     "topk_bank",
     "topk_rows",

@@ -51,6 +51,7 @@ call (BENCH_sharded.json / BENCH_quantiles.json ``session_overhead``).
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import functools
 import time
@@ -752,11 +753,17 @@ class BlockFeeder:
     (the ``StreamSession.ingest_block`` contract). Feeding through a
     feeder is bit-identical to calling ``ingest_block`` sequentially —
     only the overlap changes (pinned in tests/test_platform.py).
+
+    ``wait``: a context-manager factory entered around each of those
+    waits (a caller's span of host time blocked on the device); by
+    default it records nothing.
     """
 
-    def __init__(self, session: StreamSession, depth: int = 2):
+    def __init__(self, session: StreamSession, depth: int = 2,
+                 wait=contextlib.nullcontext):
         self.session = session
         self.depth = max(1, int(depth))
+        self.wait = wait
         self._staged: Optional[Tuple[jax.Array, jax.Array]] = None
         self._inflight: Deque = collections.deque()
 
@@ -773,7 +780,8 @@ class BlockFeeder:
         self.session.ingest_block(items, weights)
         self._inflight.append(_ready_token(self.session.state))
         while len(self._inflight) > self.depth:
-            jax.block_until_ready(self._inflight.popleft())
+            with self.wait():
+                jax.block_until_ready(self._inflight.popleft())
 
     def flush(self):
         """Dispatch the staged block, wait for the device, return state."""
@@ -781,7 +789,8 @@ class BlockFeeder:
             self._dispatch(*self._staged)
             self._staged = None
         while self._inflight:
-            jax.block_until_ready(self._inflight.popleft())
+            with self.wait():
+                jax.block_until_ready(self._inflight.popleft())
         return self.session.state
 
 

@@ -38,6 +38,7 @@ Layout contract:
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Dict, NamedTuple, Optional, Sequence, Union
 
@@ -250,24 +251,27 @@ def admit_rows(bank: SketchState, rows, spilled: SketchState) -> SketchState:
 
 
 def spill_rows(bank: SketchState, tenant: int, num_shards: int,
-               item_bits: int) -> Dict[str, Any]:
+               item_bits: int,
+               wait=contextlib.nullcontext) -> Dict[str, Any]:
     """Tagged flat numpy dict (npz-safe) of one tenant's rows.
 
     The cold-row spill format (DESIGN.md §15): the standard frequency
     triple restricted to the tenant's (S, k) row slice, plus enough
     metadata (``tenant``, ``shards``, ``item_bits``) to re-admit it into
-    the right rows of a compatible bank.
+    the right rows of a compatible bank. ``wait`` (a context-manager
+    factory) is entered around each of the three device reads.
     """
     sp = extract_rows(bank, tenant_rows(tenant, num_shards))
-    return {
+    out = {
         "layout": np.int32(_LAYOUT_FREQUENCY),
         "tenant": np.int32(tenant),
         "shards": np.int32(num_shards),
         "item_bits": np.int32(item_bits),
-        "ids": np.asarray(sp.ids),
-        "counts": np.asarray(sp.counts),
-        "errors": np.asarray(sp.errors),
     }
+    for name in ("ids", "counts", "errors"):
+        with wait():
+            out[name] = np.asarray(getattr(sp, name))
+    return out
 
 
 def admit_spill(bank: SketchState, d: Dict[str, Any]) -> SketchState:
