@@ -11,9 +11,9 @@ The loop (``tick``) is the serving analogue of the engine's decode
 step, and every stage is batched across tenants:
 
   1. **re-admission** — spilled tenants touched by this tick's traffic
-     or queries re-admit FIRST (``tenant.admit_spill`` — exact, via
-     ``state.merge`` against their cleared rows), so no update or query
-     ever sees a cold row;
+     or queries re-admit FIRST, all at once (``tenant.admit_tenants`` —
+     exact, via ``state.merge`` against their cleared rows), so no
+     update or query ever sees a cold row;
   2. **coalesced ingest** — every tenant's pending fragments (packed to
      composite keys at ``submit`` time) concatenate, in deterministic
      tenant order, with the window expiries that came due
@@ -29,9 +29,9 @@ step, and every stage is batched across tenants:
      subscriptions run the per-tenant lockstep search
      (``tenant.tenant_quantile_many``) on a composite-key dyadic bank;
   5. **eviction** — tenants idle for ``spill_after`` ticks (no traffic,
-     no subscription) spill their rows to tagged numpy dicts
-     (``tenant.spill_rows``) and their rows clear in place; the bank
-     keeps serving everyone else.
+     no subscription) spill their rows to tagged numpy dicts, all in
+     one device read (``tenant.spill_tenants``), and their rows clear
+     in place; the bank keeps serving everyone else.
 
 A tick is the service's consistency barrier: after ``tick()`` returns,
 every update submitted before it is visible to every query answered by
@@ -41,7 +41,7 @@ Telemetry: each stage that has work runs inside a span — ``admit``,
 ``ingest``, ``query``, ``subscriptions``, ``spill`` (every tick while
 spill is on: its scan for idle tenants is work) — and every point
 where the tick blocks on the device (the feeder's waits, the query and
-top-k reads, the spill reads) inside a nested ``wait`` span. A span is
+top-k reads, the spill read) inside a nested ``wait`` span. A span is
 a ``jax.profiler.TraceAnnotation`` named ``sketch.<name>`` carrying
 ``tick=<n>``, so it lands on a profiler trace's host plane, on the
 device's clock; it also adds its nanoseconds and a count to
@@ -332,8 +332,7 @@ class SketchService:
         readmit = sorted(touched & set(self._spilled))
         if readmit:
             with self._span("admit"):
-                for t in readmit:
-                    self._admit(t)
+                self._admit(readmit)
         # 2) coalesce updates + due window expiries across tenants
         if self._pending:
             with self._span("ingest"):
@@ -475,29 +474,29 @@ class SketchService:
     def _spill_idle(self) -> None:
         keep = set(self._topk_subs) | set(self._quant_subs) \
             | set(self._pending)
-        for t, last in list(self._last_active.items()):
-            if (t in keep or t in self._spilled
-                    or self._tick - last < self.spill_after):
-                continue
-            self._spill(t)
-
-    def _spill(self, tenant: int) -> None:
-        shards = self.spec.shards or 1
-        bank = self.session.state.bank
-        self._spilled[tenant] = tn.spill_rows(
-            bank, tenant, shards, self.item_bits, wait=self._wait)
-        rows = tn.tenant_rows(tenant, shards)
-        self.session.state = tn.TenantBank(bank=tn.clear_rows(bank, rows))
-        self.stats["spills"] += 1
-
-    def _admit(self, tenant: int) -> None:
-        if self.trace_blocks is not None:
-            self.trace_admits.append((len(self.trace_blocks), tenant))
-        bank = tn.admit_spill(self.session.state.bank,
-                              self._spilled.pop(tenant))
+        idle = [t for t, last in self._last_active.items()
+                if t not in keep and t not in self._spilled
+                and self._tick - last >= self.spill_after]
+        if not idle:
+            return
+        bank, spills = tn.spill_tenants(
+            self.session.state.bank, idle, self.spec.shards or 1,
+            self.item_bits, wait=self._wait, donate=self.session.donate)
         self.session.state = tn.TenantBank(bank=bank)
-        self._last_active[tenant] = self._tick
-        self.stats["admits"] += 1
+        self._spilled.update(zip(idle, spills))
+        self.stats["spills"] += len(idle)
+
+    def _admit(self, tenants: List[int]) -> None:
+        if self.trace_blocks is not None:
+            self.trace_admits.extend(
+                (len(self.trace_blocks), t) for t in tenants)
+        bank = tn.admit_tenants(
+            self.session.state.bank, [self._spilled.pop(t) for t in tenants],
+            donate=self.session.donate)
+        self.session.state = tn.TenantBank(bank=bank)
+        for t in tenants:
+            self._last_active[t] = self._tick
+        self.stats["admits"] += len(tenants)
 
     # -- synchronous conveniences ------------------------------------------
 
@@ -514,7 +513,7 @@ class SketchService:
         if self._pending or self._tickets:
             self.tick()
         if tenant is not None and tenant in self._spilled:
-            self._admit(tenant)
+            self._admit([tenant])
 
     def topk(self, tenant: int, m: int):
         """Current top-m for one tenant (raw items, counts); settles

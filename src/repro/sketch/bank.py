@@ -558,68 +558,83 @@ def update_rows(bank: SketchState, row_items: jax.Array,
 # ---------------------------------------------------------------------------
 
 def _fused_partition(bank: SketchState, items: jax.Array, weights: jax.Array,
-                     router: HashShardRouter, variant: int,
-                     owner_of=None) -> SketchState:
+                     router: HashShardRouter, variant: int) -> SketchState:
     """Fused single-launch partition ingest: global phase 1, banked phase 2.
 
     The single-sketch two-phase pipeline (blocks._phase1) run once on
     global arrays with row-aware grouping, so the B-wide sorts and the
     monitored matching are paid once — not once per row:
 
-      1. one shared sort; one global aggregation to (uids, net);
+      1. one shared sort; one global aggregation to (uids, net)
+         (``_partition_aggregate``);
       2. monitored matching for ALL rows with one searchsorted of the
          stacked (S, k) ids into the global uniques (same total work as
-         the single sketch: an id matches only in its owner row);
+         the single sketch: an id matches only in its owner row)
+         (``_match_search``);
       3. ONE packed-key partition groups residual inserts into every
          row's [units | non-units | consumed-by-fill] layout at once
          (the consumed prefix is known up front from in-row ranks);
       4. per-row slices of that one global array feed batched
          fill_empty_slots / waterfill_unit_inserts and the flat banked
          residual loop, whose trip count is max_s(non-unit_s) ≈ U/S
-         instead of U.
+         instead of U (steps 3-4: ``_partition_apply``).
 
     Per-row results are bit-identical to blocks.block_update on the
     row's own substream (each step sees exactly the row's aggregated
     multiset in the same order) — pinned against
     ``sharded.update_block_serial_reference`` by tests and
     BENCH_sharded.json.
-
-    ``owner_of`` overrides ``router.owner_of`` with a map onto the rows
-    of ``bank`` (``_fused_touched`` passes a bank of selected rows).
     """
-    S = bank.ids.shape[0]
-    owner_of = owner_of or router.owner_of
-    k = bank.ids.shape[1]
+    uids, net, valid = _partition_aggregate(items, weights,
+                                            router.universe_bits)
+    counts1, monitored = _match_search(bank, uids, net)
+    return _partition_apply(
+        SketchState(bank.ids, counts1, bank.errors), uids, net, valid,
+        router.owner_of(uids), monitored,
+        getattr(router, "monotone_owner", False), variant)
+
+
+def _partition_aggregate(items: jax.Array, weights: jax.Array,
+                         universe_bits: Optional[int]):
+    """Step 1 of the partition ingest: shared sort + in-place aggregation.
+
+    Same prefix-sum aggregation as blocks._aggregate_block but WITHOUT
+    its head-compaction sort: the fused path matches and groups directly
+    against the raw sorted block (a segment's head position stands in
+    for the compacted unique), so the one grouping sort of
+    ``_partition_apply`` does all the compaction this path ever needs.
+    Returns ``(uids, net, valid)``: the sorted block, each segment's net
+    weight (read at head positions only) and the mask of heads holding a
+    real key with a nonzero net.
+    """
     items = items.astype(jnp.int32)
     weights = weights.astype(jnp.int32)
-    B = items.shape[0]
-    if (3 * S + 1) * B >= 2**31:
-        # the row-grouping packed key is klass * B + idx with 3S + 1
-        # classes — the one partition call whose key range grows with S
-        raise ValueError(
-            f"fused partition update needs (3*rows+1)*block < 2^31 for the "
-            f"packed grouping sort; got rows={S}, block={B}. Use "
-            f"path='vmap' (or fewer rows per launch).")
-
-    # -- 1. shared sort + in-place segment aggregation ---------------------
-    # Same prefix-sum aggregation as blocks._aggregate_block but WITHOUT
-    # its head-compaction sort: the fused path matches and groups
-    # directly against the raw sorted block (a segment's head position
-    # stands in for the compacted unique), so the one grouping sort in
-    # step 3 does all the compaction this path ever needs.
-    order = sort_block(items, router.universe_bits)
+    order = sort_block(items, universe_bits)
     uids = items[order]      # sorted; segment heads carry the uniques
-    wts = weights[order]
-    idx = jnp.arange(B, dtype=jnp.int32)
-    head, net = segment_nets(uids[None, :], wts[None, :])
-    head, net = head[0], net[0]  # per-unique net, valid at head positions
-    valid = head & (uids >= 0) & (net != 0)
-    owner = owner_of(uids)  # read at head positions only
+    head, net = segment_nets(uids[None, :], weights[order][None, :])
+    head, net = head[0], net[0]
+    return uids, net, head & (uids >= 0) & (net != 0)
 
-    # -- 2. monitored matching, all rows at once ---------------------------
-    # searchsorted returns the FIRST occurrence = the segment head; the
-    # (flat_ids >= 0) guard keeps EMPTY slots from matching -1 padding
-    # items (the compacted path got this from its sentinel remap).
+
+def _match_search(bank: SketchState, uids: jax.Array, net: jax.Array):
+    """Step 2 of the partition ingest: the monitored match by search.
+
+    One searchsorted of every slot id of the bank into the sorted block:
+    it returns the FIRST occurrence = the segment head; the
+    (flat_ids >= 0) guard keeps EMPTY slots from matching -1 padding
+    items (the compacted path got this from its sentinel remap). Returns
+    the counts after the monitored add and the (B,) ``monitored`` mask
+    of matched heads.
+
+    The whole-bank path keeps this search rather than
+    ``_match_owner_rows``: most of its banks have few rows (at most one
+    chunk, or a hash-sharded single sketch), where the owner-row
+    compare, sliced to 3S keys at a time, would take B/3S dependent
+    trips against ceil(log2(B+1)) search levels over S·k slots. Sharded
+    tenant banks, its one wide case, take no benchmark cell yet.
+    """
+    S, k = bank.ids.shape
+    B = uids.shape[0]
     flat_ids = bank.ids.reshape(-1)
     pos = jnp.clip(jnp.searchsorted(uids, flat_ids), 0, B - 1)
     match = (uids[pos] == flat_ids) & (flat_ids >= 0)
@@ -629,6 +644,63 @@ def _fused_partition(bank: SketchState, items: jax.Array, weights: jax.Array,
         .at[jnp.where(match, pos, B)]
         .set(True, mode="drop")
     )
+    return counts1, monitored
+
+
+def _match_owner_rows(ids: jax.Array, uids: jax.Array, valid: jax.Array,
+                      owner: jax.Array):
+    """The monitored match by owner row: step 2 of the touched-rows
+    ingest, once per block.
+
+    An id is monitored, if at all, in its owner row only, so each head
+    ``uids[i]`` is compared with the ``k`` ids of row ``owner[i]`` — a
+    row gather of contiguous rows, as ``query_rows`` reads — instead of
+    binary-searching every slot of the bank into the block. Heads that
+    are not ``valid`` (padding, weight 0, a zero net) never match; the
+    (ids >= 0) guard keeps EMPTY and BLOCKED slots out, and a key past
+    the bank's last row, clipped onto it, finds no id of its own there.
+    The compare reads a (B, k) block of ids; where that would outgrow
+    the bank itself (B > 3R), the keys are compared 3R at a time.
+
+    Returns ``(slot, monitored)``: each head's matched slot in its owner
+    row (clipped into the row where there is none) and the (B,) mask of
+    matched heads. On a bank whose ids sit in their owner rows these are
+    the slots ``_match_search`` adds to, and its ``monitored`` mask
+    wherever ``valid`` holds (the only heads it is read at).
+    """
+    R, k = ids.shape
+    B = uids.shape[0]
+    lane = jnp.arange(k, dtype=jnp.int32)
+
+    def first_slot(x):
+        row, key = x
+        ids_r = ids[row]
+        return jnp.where((ids_r == key) & (ids_r >= 0), lane, k).min()
+
+    slot = jax.lax.map(first_slot, (jnp.clip(owner, 0, R - 1), uids),
+                       batch_size=0 if B <= 3 * R else 3 * R)
+    return jnp.minimum(slot, k - 1), valid & (slot < k)
+
+
+def _partition_apply(bank: SketchState, uids: jax.Array, net: jax.Array,
+                     valid: jax.Array, owner: jax.Array, monitored: jax.Array,
+                     monotone: bool, variant: int) -> SketchState:
+    """Steps 3-4 of the partition ingest, after the monitored add.
+
+    ``bank``'s counts already hold the monitored add; ``uids``, ``net``,
+    ``valid`` and ``monitored`` are the aggregated block and its match,
+    and ``owner`` maps each sorted key onto a row of ``bank``.
+    ``monotone``: the owner is non-decreasing in key order.
+    """
+    S = bank.ids.shape[0]
+    B = uids.shape[0]
+    if (3 * S + 1) * B >= 2**31:
+        # the row-grouping packed key is klass * B + idx with 3S + 1
+        # classes — the one partition call whose key range grows with S
+        raise ValueError(
+            f"fused partition update needs (3*rows+1)*block < 2^31 for the "
+            f"packed grouping sort; got rows={S}, block={B}. Use "
+            f"path='vmap' (or fewer rows per launch).")
 
     # -- 3. residual classification + ONE row-major grouping sort ----------
     # blocks._phase1 builds the [units | non-units | consumed] layout per
@@ -641,7 +713,7 @@ def _fused_partition(bank: SketchState, items: jax.Array, weights: jax.Array,
     owner_c = jnp.clip(owner, 0, S - 1)
     res_ins = valid & ~monitored & (net > 0)
     empties_s = (bank.ids == EMPTY).sum(axis=1)
-    if getattr(router, "monotone_owner", False):
+    if monotone:
         # owner is non-decreasing in sorted-key order (tenant-major
         # composite keys): each row's entries form one contiguous run,
         # so in-row ranks and per-row tallies are prefix-sum
@@ -710,7 +782,7 @@ def _fused_partition(bank: SketchState, items: jax.Array, weights: jax.Array,
     # per-row offsets — no per-row (S, B) slices materialize.
     ids1, cnt1, err1, _ = jax.vmap(
         fill_empty_slots, in_axes=(0, 0, 0, None, None, 0, 0))(
-        bank.ids, counts1, bank.errors, h_uids, h_net, i0_s, coff_s)
+        bank.ids, bank.counts, bank.errors, h_uids, h_net, i0_s, coff_s)
     ids1, cnt1, err1 = jax.vmap(
         waterfill_unit_inserts, in_axes=(0, 0, 0, None, 0, 0))(
         ids1, cnt1, err1, h_uids, mu_s, uoff_s)
@@ -725,9 +797,11 @@ def touched_chunk_rows(block: int, k: int) -> int:
 
     The smallest power of two, and at least one 8-row sublane tile, whose
     rows of ``k`` counters hold ``block * log2(block)`` slots: one pass
-    over a chunk's counters (the monitored match, a residual-loop trip)
-    then costs about what the block's own sorts do, so neither the
-    per-chunk sorts nor the rows' lockstep work dominates.
+    over a chunk's counters (the fill, water-fill or a residual-loop
+    trip) then costs about what the chunk's grouping sort of the block
+    does, so neither the per-chunk sort nor the rows' lockstep work
+    dominates. The monitored match does not enter it: it runs once per
+    block, before the chunks.
     """
     target = block * max(1, block.bit_length() - 1)
     rows = -(-target // k)
@@ -758,22 +832,28 @@ def ingest_chunks(router: Router, block: int, k: int, rows_reached):
 
 def _fused_touched(bank: SketchState, items: jax.Array, weights: jax.Array,
                    router: TenantRouter, variant: int) -> SketchState:
-    """``_fused_partition`` over the rows the block touches, a chunk at a time.
+    """The partition ingest over the rows the block touches, a chunk at a
+    time.
 
-    Every phase of the partition ingest costs O(R·k) per block — the
+    Every phase of the whole-bank ingest costs O(R·k) per block — the
     monitored match, the fill and water-fill and each residual-loop trip
     pass over the whole bank — though a block of B items reaches at most
-    B rows. Here the rows holding a nonzero weight are listed in
-    ascending order and ingested ``touched_chunk_rows(B, k)`` at a time:
-    each chunk is gathered into a bank of its own, fed the block with the
-    weights of every other row set to zero, and scattered back. Rows are
-    independent and a row with no nonzero weight is left unchanged by an
-    ingest, so the result is bit-identical to the whole-bank ingest,
-    whatever number of rows the block reaches.
+    B rows. Here phase 1 runs ONCE per block: the shared sort and
+    aggregation (``_partition_aggregate``) and the monitored match by
+    owner row (``_match_owner_rows``). The rows holding a nonzero weight
+    are then listed in ascending order and ingested
+    ``touched_chunk_rows(B, k)`` at a time: each chunk is gathered into a
+    bank of its own, takes its matched heads' monitored add, runs the
+    rest of the ingest (``_partition_apply``) on the aggregated block
+    with every other row's keys masked out, and is scattered back. Rows
+    are independent, each row's monitored add precedes its fill,
+    water-fill and residual loop, and a row with no nonzero weight is
+    left unchanged by an ingest, so the result is bit-identical to the
+    whole-bank ingest, whatever number of rows the block reaches.
 
     Needs a monotone owner: the owner's rank among a chunk's rows is then
-    non-decreasing in key order too, as ``_fused_partition``'s run
-    boundaries require. Items of other rows carry weight 0 and never
+    non-decreasing in key order too, as ``_partition_apply``'s run
+    boundaries require. Keys of other rows are masked out and never
     count, whatever rank they get; padding slots of the last chunk
     (index R) read a clipped row and are dropped on the way back.
     """
@@ -790,15 +870,29 @@ def _fused_touched(bank: SketchState, items: jax.Array, weights: jax.Array,
     rows = jnp.nonzero(hit, size=size, fill_value=R)[0].astype(jnp.int32)
     n_chunks = (hit.sum(dtype=jnp.int32) + cap - 1) // cap
 
+    uids, net, valid = _partition_aggregate(items, weights,
+                                            router.universe_bits)
+    owner = router.owner_of(uids)
+    slot, monitored = _match_owner_rows(bank.ids, uids, valid, owner)
+
     def chunk(c, bank):
         sel = jax.lax.dynamic_slice(rows, (c * cap,), (cap,))
-        mine = sel[jnp.clip(jnp.searchsorted(sel, owner), 0, cap - 1)] == owner
+        rank = jnp.searchsorted(sel, owner).astype(jnp.int32)
+        rank_c = jnp.clip(rank, 0, cap - 1)
+        mine = sel[rank_c] == owner
         sub = jax.tree.map(lambda x: jnp.take(x, sel, axis=0, mode="clip"),
                            bank)
-        out = _fused_partition(
-            sub, items, jnp.where(mine, weights, 0), router, variant,
-            owner_of=lambda key: jnp.searchsorted(
-                sel, router.owner_of(key)).astype(jnp.int32))
+        # the monitored add of the chunk's matched heads: one (B,) scatter
+        # into the chunk's rows (a scatter into the whole (R, k) bank
+        # makes the TPU relayout every count); a row's ids are unique, so
+        # no slot is written twice
+        counts1 = sub.counts.at[
+            jnp.where(monitored & mine, rank_c, cap), slot].set(
+                sat_add(sub.counts[rank_c, slot], net), mode="drop")
+        out = _partition_apply(
+            SketchState(sub.ids, counts1, sub.errors), uids,
+            jnp.where(mine, net, 0), valid & mine, rank, monitored, True,
+            variant)
         return jax.tree.map(
             lambda x, y: x.at[sel].set(y, mode="drop"), bank, out)
 

@@ -250,6 +250,17 @@ def admit_rows(bank: SketchState, rows, spilled: SketchState) -> SketchState:
     )
 
 
+def _spill_dict(tenant: int, num_shards: int, item_bits: int,
+                ids, counts, errors) -> Dict[str, Any]:
+    return {
+        "layout": np.int32(_LAYOUT_FREQUENCY),
+        "tenant": np.int32(tenant),
+        "shards": np.int32(num_shards),
+        "item_bits": np.int32(item_bits),
+        "ids": ids, "counts": counts, "errors": errors,
+    }
+
+
 def spill_rows(bank: SketchState, tenant: int, num_shards: int,
                item_bits: int,
                wait=contextlib.nullcontext) -> Dict[str, Any]:
@@ -262,20 +273,15 @@ def spill_rows(bank: SketchState, tenant: int, num_shards: int,
     factory) is entered around each of the three device reads.
     """
     sp = extract_rows(bank, tenant_rows(tenant, num_shards))
-    out = {
-        "layout": np.int32(_LAYOUT_FREQUENCY),
-        "tenant": np.int32(tenant),
-        "shards": np.int32(num_shards),
-        "item_bits": np.int32(item_bits),
-    }
+    out = {}
     for name in ("ids", "counts", "errors"):
         with wait():
             out[name] = np.asarray(getattr(sp, name))
-    return out
+    return _spill_dict(tenant, num_shards, item_bits, **out)
 
 
-def admit_spill(bank: SketchState, d: Dict[str, Any]) -> SketchState:
-    """Re-admit a :func:`spill_rows` dict into its tenant's rows."""
+def _spilled_rows(d: Dict[str, Any]):
+    """The rows and (S, k) triple of a :func:`spill_rows` dict."""
     for key in ("tenant", "shards", "ids", "counts", "errors"):
         if key not in d:
             raise ValueError(
@@ -284,12 +290,100 @@ def admit_spill(bank: SketchState, d: Dict[str, Any]) -> SketchState:
                 f"ids/counts/errors triple")
     num_shards = int(np.asarray(d["shards"]))
     rows = tenant_rows(int(np.asarray(d["tenant"])), num_shards)
-    spilled = SketchState(
-        ids=jnp.asarray(np.asarray(d["ids"]), jnp.int32),
-        counts=jnp.asarray(np.asarray(d["counts"]), jnp.int32),
-        errors=jnp.asarray(np.asarray(d["errors"]), jnp.int32),
-    )
-    return admit_rows(bank, rows, spilled)
+    return rows, [np.asarray(d[name], np.int32)
+                  for name in ("ids", "counts", "errors")]
+
+
+def admit_spill(bank: SketchState, d: Dict[str, Any]) -> SketchState:
+    """Re-admit a :func:`spill_rows` dict into its tenant's rows."""
+    rows, triple = _spilled_rows(d)
+    return admit_rows(bank, rows,
+                      SketchState(*(jnp.asarray(x) for x in triple)))
+
+
+# Tenants a launch of the batched spill and re-admission: one compiled
+# shape whatever a tick's count, the last launch padded with row index
+# R, which the scatters of clear_rows/admit_rows drop.
+SPILL_BATCH = 32
+
+
+@functools.lru_cache(maxsize=None)
+def _batch_moves(donate: bool):
+    """(evict, admit) over a batch of rows; ``donate``: the bank is
+    donated, so its rows clear or re-admit in place."""
+    def evict(bank, rows):
+        return clear_rows(bank, rows), extract_rows(bank, rows)
+
+    argnums = (0,) if donate else ()
+    return (jax.jit(evict, donate_argnums=argnums),
+            jax.jit(admit_rows, donate_argnums=argnums))
+
+
+def _padded_rows(rows: Sequence[np.ndarray], num_shards: int, R: int):
+    rows = np.concatenate(rows)
+    per = SPILL_BATCH * num_shards
+    return np.pad(rows, (0, -len(rows) % per), constant_values=R
+                  ).astype(np.int32), per
+
+
+def spill_tenants(bank: SketchState, tenants: Sequence[int],
+                  num_shards: int, item_bits: int,
+                  wait=contextlib.nullcontext, donate: bool = False):
+    """Spill several tenants at once: ``(bank, dicts)``.
+
+    ``dicts[i]`` is :func:`spill_rows` of ``tenants[i]`` and ``bank`` is
+    :func:`clear_rows` of all their rows, in one launch per
+    ``SPILL_BATCH`` tenants and ONE device read of every launch's rows
+    (``wait`` is entered around it). ``donate``: donate ``bank`` where
+    the platform reuses donated buffers
+    (``repro.platform.donate_state_buffers``), so the rows clear in
+    place instead of the bank being copied.
+    """
+    if not len(tenants):
+        return bank, []
+    from repro.platform import donate_state_buffers
+
+    evict, _ = _batch_moves(bool(donate) and donate_state_buffers())
+    rows, per = _padded_rows([tenant_rows(t, num_shards) for t in tenants],
+                             num_shards, bank.ids.shape[0])
+    payload = []
+    for s in range(0, len(rows), per):
+        bank, sp = evict(bank, rows[s:s + per])
+        payload.append(sp)
+    with wait():
+        payload = jax.device_get(payload)
+    ids, counts, errors = (np.concatenate([p[f] for p in payload])
+                           for f in range(3))
+    return bank, [
+        _spill_dict(t, num_shards, item_bits,
+                    *(x[i * num_shards:(i + 1) * num_shards].copy()
+                      for x in (ids, counts, errors)))
+        for i, t in enumerate(tenants)]
+
+
+def admit_tenants(bank: SketchState, dicts: Sequence[Dict[str, Any]],
+                  donate: bool = False) -> SketchState:
+    """:func:`admit_spill` of several spill dicts of distinct tenants,
+    in one launch per ``SPILL_BATCH`` tenants; ``donate`` as in
+    :func:`spill_tenants`."""
+    if not len(dicts):
+        return bank
+    from repro.platform import donate_state_buffers
+
+    _, admit = _batch_moves(bool(donate) and donate_state_buffers())
+    rows, triples = zip(*(_spilled_rows(d) for d in dicts))
+    num_shards = len(rows[0])
+    rows, per = _padded_rows(rows, num_shards, bank.ids.shape[0])
+    pad = len(rows) - num_shards * len(dicts)
+    ids, counts, errors = (
+        np.pad(np.concatenate([t[f] for t in triples]), ((0, pad), (0, 0)),
+               constant_values=c)
+        for f, c in ((0, int(EMPTY)), (1, 0), (2, 0)))
+    for s in range(0, len(rows), per):
+        bank = admit(bank, rows[s:s + per],
+                     SketchState(ids[s:s + per], counts[s:s + per],
+                                  errors[s:s + per]))
+    return bank
 
 
 # ---------------------------------------------------------------------------
@@ -491,6 +585,9 @@ __all__ = [
     "admit_rows",
     "spill_rows",
     "admit_spill",
+    "SPILL_BATCH",
+    "spill_tenants",
+    "admit_tenants",
     "tenant_rank_many",
     "tenant_mass",
     "tenant_quantile_many",

@@ -113,13 +113,14 @@ def test_host_syncs_at_the_spill_reads():
     svc = _service(spill_after=1)
     svc.submit(0, [1, 2, 3])
     svc.submit(1, [5])
+    svc.submit(2, [6])
     svc.tick()
     snap = dict(svc.stats)
     svc.submit(0, [1])
-    svc.tick()                              # tenant 1 idle: it spills
+    svc.tick()                              # tenants 1, 2 idle: they spill
     d = _delta(svc, snap)
-    assert d["spills"] == 1 and d["blocks"] == 1
-    assert d["wait_n"] == 1 + 3             # the flush, ids/counts/errors
+    assert d["spills"] == 2 and d["blocks"] == 1
+    assert d["wait_n"] == 1 + 1             # the flush, one read of both
 
 
 def _reference_chunks(svc: SketchService, blocks) -> int:

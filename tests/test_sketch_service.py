@@ -155,6 +155,34 @@ def test_save_load_resume_matches_uninterrupted():
                                       c.query(t, probe).result())
 
 
+def test_spill_wave_wider_than_a_launch_matches_twin(donated):
+    """More tenants than one batched launch holds spill in one tick and
+    re-admit in one tick, on the donating path; every answer matches a
+    twin that never spills."""
+    T = tn.SPILL_BATCH + 8
+    spec = _freq_spec(T=T, k_t=8)
+    svc = SketchService(spec, block=64, spill_after=1)
+    twin = SketchService(spec, block=64, donate=False)
+    rng = np.random.default_rng(3)
+    for t in range(T):
+        items = rng.integers(0, 8, 6)
+        for s in (svc, twin):
+            s.submit(t, items)
+    for s in (svc, twin):
+        s.tick()
+        s.submit(0, [1])
+        s.tick()
+    assert set(svc._spilled) == set(range(1, T))
+    probe = np.arange(8)
+    tickets = [(svc.query(t, probe), twin.query(t, probe))
+               for t in range(T)]
+    svc.tick()
+    twin.tick()
+    assert svc.stats["admits"] == T - 1
+    for a, b in tickets:
+        np.testing.assert_array_equal(a.result(), b.result())
+
+
 def test_save_load_roundtrips_spilled_tenants():
     svc = SketchService(_freq_spec(), block=64, spill_after=1)
     svc.submit(3, [4, 4, 5])

@@ -242,39 +242,142 @@ def test_fused_matches_serial_reference(variant_id, shards):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@pytest.mark.parametrize("variant_id", [1, 2])
-@pytest.mark.parametrize("chunks", [1, 4])
-def test_touched_rows_path_matches_whole_bank(variant_id, chunks):
+# block shapes of the touched-rows cases: "mixed" is random traffic;
+# "repeats" repeats already-monitored keys inside a block (segment
+# heads); "padding" adds -1 padding and weight-0 keys; "caps" gives the
+# tenants capacities below k (BLOCKED slots); "empties" keeps EMPTY slots
+# in every row; "deletes" deletes monitored keys (a negative net).
+TOUCHED_CASES = ["mixed", "repeats", "padding", "caps", "empties", "deletes"]
+
+
+def _touched_block(case, rng, tenants, heavy, B, spread, step):
+    """(tenant, item, weight) arrays of one block of a touched-rows case:
+    every tenant of ``tenants`` gets an entry, ``heavy`` gets the rest."""
+    t = np.concatenate([tenants, rng.choice(heavy, B - spread)])
+    items = rng.integers(0, 200, B)
+    w = rng.choice([-1, 1, 1, 2], B)
+    w[spread:][rng.random(B - spread) < 0.1] = 0
+    if case == "repeats":
+        # a few keys per tenant, inserted again block after block
+        items = rng.integers(0, 6, B)
+        w = rng.choice([-1, 1, 1, 1, 2], B)
+    elif case == "empties":
+        items = rng.integers(0, 24, B)   # < k distinct keys per tenant
+    elif case == "deletes":
+        items = rng.integers(0, 40, B)
+        w = (np.where(rng.random(B) < 0.85, -1, 1) if step % 2
+             else rng.choice([1, 2, 3], B))
+    return t, items, w
+
+
+@pytest.mark.parametrize("chunks,variant_id,case", [
+    pytest.param(c, v, case, id=f"{c}-{v}" if case == "mixed"
+                 else f"{c}-{v}-{case}")
+    for case in TOUCHED_CASES for c in (1, 4) for v in (1, 2)])
+def test_touched_rows_path_matches_whole_bank(variant_id, chunks, case):
     """A bank wider than one chunk of ``touched_chunk_rows``: blocks
     reaching one chunk's worth of tenants, or four chunks' (the last
     partial), equal the whole-bank ingest bit for bit, through
-    evictions and deletions."""
+    evictions and deletions, in every case of ``TOUCHED_CASES``."""
     B, k = 256, 64
     cap = bk.touched_chunk_rows(B, k)
     T = 4 * cap + 8
     spread = (chunks - 1) * cap + cap // 2 + 4
     router = tn.router_for(T, BITS, 1)
-    bank = tn.init_tenants(k, num_tenants=T).bank
+    assert bk.takes_touched(router, B, k)
+    rng = np.random.default_rng([chunks, TOUCHED_CASES.index(case)])
+    caps = (rng.integers(1, k + 1, T).tolist() if case == "caps" else k)
+    bank = tn.init_tenants(caps, num_tenants=T).bank
     ref = bank
-    rng = np.random.default_rng(chunks)
     whole = jax.jit(bk._fused_partition, static_argnums=(3, 4))
     heavy = rng.choice(T, 2, replace=False)
-    for _ in range(4):
-        # every chosen tenant gets an item; two fixed ones get the rest
+    grew = dropped = False   # a kept id's count rose / fell in a block
+    for step in range(4):
         others = rng.choice(np.setdiff1d(np.arange(T), heavy), spread - 2,
                             replace=False)
-        tenants = np.concatenate([heavy, others])
-        t = np.concatenate([tenants, rng.choice(heavy, B - spread)])
-        items = rng.integers(0, 200, B)
-        w = rng.choice([-1, 1, 1, 2], B)
-        w[spread:][rng.random(B - spread) < 0.1] = 0
-        keys = jnp.asarray(tn.pack_keys(t, items, BITS).astype(np.int32))
+        t, items, w = _touched_block(case, rng, np.concatenate(
+            [heavy, others]), heavy, B, spread, step)
+        keys = tn.pack_keys(t, items, BITS)
+        if case == "padding":
+            pad = rng.random(B) < 0.2
+            keys[pad] = -1
+            w[pad] = 0
+            w[rng.random(B) < 0.1] = 0
+        keys = jnp.asarray(keys.astype(np.int32))
         w = jnp.asarray(w.astype(np.int32))
         bank = bk.update_block_fused(bank, keys, w, router, variant_id)
-        ref = whole(ref, keys, w, router, variant_id)
-    assert int((np.asarray(ref.errors) > 0).sum()) > 0  # evictions ran
+        prev, ref = ref, whole(ref, keys, w, router, variant_id)
+        kept = (np.asarray(ref.ids) == np.asarray(prev.ids)) \
+            & (np.asarray(prev.ids) >= 0)
+        delta = np.asarray(ref.counts) - np.asarray(prev.counts)
+        grew |= bool((kept & (delta > 0)).any())
+        dropped |= bool((kept & (delta < 0)).any())
+    ids, errors = np.asarray(ref.ids), np.asarray(ref.errors)
+    if case == "empties":
+        assert ((ids == -1).sum(1) > 0).all()   # every row kept an EMPTY
+    elif case == "repeats":
+        assert grew
+    elif case == "deletes":
+        assert dropped
+    else:
+        assert int((errors > 0).sum()) > 0  # evictions ran
+    if case == "caps":
+        assert int((ids == -2).sum()) > 0
     for a, b in zip(bank, ref):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("rows,block", [(40, 64), (8, 64)])
+def test_owner_row_match_equals_search_match(rows, block):
+    """The touched path's owner-row match gives the slots and the
+    ``monitored`` mask the whole-bank searchsorted match gives, on random
+    banks with EMPTY and BLOCKED slots, whether the keys are compared all
+    at once (block <= 3 rows) or a slice at a time (block > 3 rows)."""
+    k = 16
+    rng = np.random.default_rng(rows)
+    router = tn.router_for(rows, BITS, 1)
+    for _ in range(3):
+        caps = rng.integers(1, k + 1, rows)
+        ids = np.full((rows, k), -2, np.int64)
+        counts = np.full((rows, k), 2**31 - 1, np.int64)
+        for r, c in enumerate(caps):
+            live = rng.choice(UNIVERSE, rng.integers(0, c + 1), replace=False)
+            ids[r, :c] = -1
+            counts[r, :c] = 0
+            slots = rng.choice(c, len(live), replace=False)
+            ids[r, slots] = tn.pack_keys(np.full(len(live), r), live, BITS)
+            counts[r, slots] = rng.integers(1, 50, len(live))
+        bank = bk.SketchState(jnp.asarray(ids, jnp.int32),
+                              jnp.asarray(counts, jnp.int32),
+                              jnp.zeros((rows, k), jnp.int32))
+        # half the keys are monitored ids (repeated), the rest random
+        # keys of any tenant the key width allows, padding, weight 0
+        monitored_ids = ids[ids >= 0]
+        keys = np.where(
+            rng.random(block) < 0.5, rng.choice(monitored_ids, block),
+            tn.pack_keys(rng.integers(0, 1 << router.tenant_bits, block),
+                         rng.integers(0, UNIVERSE, block), BITS))
+        w = rng.choice([-2, -1, 0, 1, 2], block)
+        pad = rng.random(block) < 0.1
+        keys[pad], w[pad] = -1, 0
+        uids, net, valid = bk._partition_aggregate(
+            jnp.asarray(keys, jnp.int32), jnp.asarray(w, jnp.int32),
+            router.universe_bits)
+        c_search, m_search = bk._match_search(bank, uids, net)
+        owner = np.asarray(router.owner_of(uids))
+        slot, m_owner = map(np.asarray, bk._match_owner_rows(
+            bank.ids, uids, valid, router.owner_of(uids)))
+        m_search = np.asarray(m_search & valid)
+        assert m_search.any()
+        np.testing.assert_array_equal(m_owner, m_search)
+        # the matched slots hold the keys, and adding each matched net
+        # there gives the counts the search match gives
+        np.testing.assert_array_equal(ids[owner[m_owner], slot[m_owner]],
+                                      np.asarray(uids)[m_owner])
+        c_owner = counts.copy()
+        np.add.at(c_owner, (owner[m_owner], slot[m_owner]),
+                  np.asarray(net)[m_owner])
+        np.testing.assert_array_equal(c_owner, np.asarray(c_search))
 
 
 @given(seed=hyp_st.integers(0, 2**16), delete_frac=hyp_st.sampled_from(
@@ -309,11 +412,11 @@ def test_tenant_caps_row_capacities():
 # Spill / exact re-admission
 # ---------------------------------------------------------------------------
 
-def _built_bank(T=4, S=1, k_t=6, seed=5):
+def _built_bank(T=4, S=1, k_t=6, seed=5, n=400):
     spec = api.SketchSpec(kind="frequency", k=T * k_t, bits=BITS,
                           tenants=T, shards=S if S > 1 else None)
     st_mt = api.make(spec)
-    frags = _interleave(_tenant_streams(seed, T), seed=seed)
+    frags = _interleave(_tenant_streams(seed, T, n=n), seed=seed)
     blocks, _ = _blocks_of(frags, T)
     for ci, cw in blocks:
         st_mt = api.update(spec, st_mt, jnp.asarray(ci), jnp.asarray(cw))
@@ -361,6 +464,43 @@ def test_spill_admit_roundtrip_preserves_queries(shards):
         rows = tn.tenant_rows(t, S)
         np.testing.assert_array_equal(np.asarray(st_mt.bank.ids[rows]),
                                       np.asarray(admitted.bank.ids[rows]))
+
+
+@pytest.mark.parametrize("shards", [1, 2])
+@pytest.mark.parametrize("donate", [False, True])
+def test_batched_spill_admit_equals_one_tenant_at_a_time(
+        shards, donate, monkeypatch):
+    """spill_tenants / admit_tenants over more tenants than one launch
+    holds give, bit for bit, each tenant's spill_rows dict, the bank
+    clear_rows leaves and the bank admit_spill one tenant at a time
+    builds; a donated bank is consumed."""
+    from repro import platform
+
+    monkeypatch.setattr(platform, "donate_state_buffers", lambda: True)
+    T = tn.SPILL_BATCH + 5
+    spec, st_mt = _built_bank(T=T, S=shards, n=60)
+    S = spec.shards or 1
+    tenants = [int(t) for t in np.random.default_rng(1).permutation(T)[2:]]
+    rows = np.concatenate([tn.tenant_rows(t, S) for t in tenants])
+    want = [tn.spill_rows(st_mt.bank, t, S, BITS) for t in tenants]
+    cleared = tn.clear_rows(st_mt.bank, rows)
+    readmitted = cleared
+    for d in want[::-1]:
+        readmitted = tn.admit_spill(readmitted, d)
+
+    bank, got = tn.spill_tenants(st_mt.bank, tenants, S, BITS,
+                                 donate=donate)
+    assert st_mt.bank.ids.is_deleted() == donate
+    assert [int(d["tenant"]) for d in got] == tenants
+    for d, e in zip(got, want):
+        assert d.keys() == e.keys()
+        for key in d:
+            np.testing.assert_array_equal(d[key], e[key])
+    for x, y in zip(bank, cleared):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    back = tn.admit_tenants(bank, got[::-1], donate=donate)
+    for x, y in zip(back, readmitted):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
 def test_admit_spill_rejects_truncated_dict():

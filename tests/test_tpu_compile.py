@@ -82,6 +82,26 @@ def test_tenant_bank_ingest(one_chip):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
 
 
+@pytest.mark.parametrize("move", ["evict", "admit"])
+def test_batched_spill_moves_in_place(one_chip, move):
+    """The service's donated batched spill and re-admission at 16,384 x
+    2,048 update the bank in place: it aliases the output, and no temp
+    comes near a copy of it."""
+    evict, admit = tn._batch_moves(True)
+    n = tn.SPILL_BATCH
+    rows = _sds(one_chip, (n,))
+    bank = _tenant_bank(one_chip).bank
+    if move == "evict":
+        c = evict.lower(bank, rows).compile()
+    else:
+        c = admit.lower(bank, rows, SketchState(
+            *[_sds(one_chip, (n, K_TENANT))] * 3)).compile()
+    mem = c.memory_analysis()
+    state = 3 * TENANTS * K_TENANT * 4
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < TENANTS * K_TENANT * 4 // 16
+
+
 @pytest.mark.parametrize("n_keys", [128, 65536])
 def test_query_rows(one_chip, n_keys):
     router = bk.TenantRouter(TENANTS, BITS, 1)
