@@ -6,11 +6,12 @@
 
 For each seed, one run of the cell as ``run.py`` makes it (its window,
 its load), compared twice: as the program served it (the readings that
-set each limit's lower end), and with the control in the program's
-place: the reference answering one tick stale (``chipbench.check``),
-which has to fail. All seeds run in one process, so set-up compiles
-once. Prints one JSON line per seed, then the largest sound reading
-and the smallest control reading of each compared number.
+set each limit's lower end), and with the control of the
+configuration's kind in the program's place (``compare(control=True)``
+of ``chipbench/kinds/<kind>.py``), which has to fail. All seeds run in
+one process, so set-up compiles once. Prints one JSON line per seed,
+then the largest sound reading and the smallest control reading of each
+compared number.
 """
 from __future__ import annotations
 

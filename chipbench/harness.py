@@ -1,12 +1,14 @@
 """One run of one cell: set-up, the measured window, and what it left.
 
 The cell, its configuration and its traffic mix are found by name in
-``BENCHMARK.json``. Set-up generates the traffic from the seed, builds
-``serve.SketchService`` over the configuration, and warms up every
-program shape the window uses: the ingest, the point-query pad sizes
-the traffic can reach, the batched top-k and the spill and re-admission
-programs. The window then drives the service's public entry points
-(``submit``, ``query``, ``subscribe_topk``, ``tick``) from one thread:
+``BENCHMARK.json``, and what is particular to the configuration's kind
+of sketch in ``chipbench/kinds/<kind>.py``. Set-up generates the traffic
+from the seed, builds the kind's ``serve.SketchService`` over the
+configuration, with its subscriptions, and warms up every program shape
+the window uses: the ingest, the point-query pad sizes the traffic can
+reach, and the kind's own programs. The window then drives the
+service's public entry points (``submit``, ``query``, ``tick``) from one
+thread:
 
 * open loop: every operation is due at a fixed time from the window's
   start; the driver submits each once it is due, and ticks when the next
@@ -28,11 +30,14 @@ import dataclasses
 import gc
 import json
 import os
+import resource
 import time
+from time import perf_counter_ns
 from typing import Dict, List, Optional
 
 import numpy as np
 
+from chipbench import kinds
 from chipbench.traffic import QUERY, UPDATE, Mix, Traffic, generate
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
@@ -42,20 +47,28 @@ DRAIN_S = 60.0
 
 @dataclasses.dataclass(frozen=True)
 class Config:
+    """What the harness reads of a configuration file; ``params`` keeps
+    every other key of the file for the kind module. ``kind`` is
+    required: it names ``chipbench/kinds/<kind>.py``."""
+
     name: str
+    kind: str
     tenants: int
-    k_per_tenant: int
-    bits: int
     block: int
     spill_after: Optional[int] = None
     flush_interval_ms: Optional[float] = None
+    params: dict = dataclasses.field(default_factory=dict)
 
     @staticmethod
     def load(path: str) -> "Config":
         with open(path) as f:
             d = json.load(f)
-        fields = {f.name for f in dataclasses.fields(Config)}
-        return Config(**{k: v for k, v in d.items() if k in fields})
+        if "kind" not in d:
+            raise ValueError(f"{path}: a configuration names its kind")
+        fields = {f.name for f in dataclasses.fields(Config)} - {"params"}
+        return Config(**{k: v for k, v in d.items() if k in fields},
+                      params={k: v for k, v in d.items()
+                              if k not in fields})
 
 
 @dataclasses.dataclass
@@ -128,6 +141,10 @@ class Window:
     unacknowledged: int = 0
     behind_at_close: int = 0          # operations due but not submitted
     wraps: int = 0
+    # the host under the window: each collector pause (generation,
+    # seconds) and the page faults (minor, major) of this process
+    gc_pauses: List[tuple] = dataclasses.field(default_factory=list)
+    page_faults: tuple = (0, 0)
 
 
 class Driver:
@@ -135,29 +152,19 @@ class Driver:
 
     def __init__(self, cell: Cell, seed: int, seconds: float,
                  annotate=None):
-        from repro.serve import SketchService
-        from repro.sketch import api
-
         c = cell.config
         self.cell, self.seed, self.seconds = cell, int(seed), float(seconds)
+        self.kind = kinds.load(c.kind)
         t0 = time.perf_counter()
         self.traffic: Traffic = generate(cell.mix, c.tenants, seed, seconds)
         t1 = time.perf_counter()
-        self.spec = api.SketchSpec(kind="frequency",
-                                   k=c.tenants * c.k_per_tenant, bits=c.bits,
-                                   tenants=c.tenants)
-        self.svc = SketchService(self.spec, block=c.block,
-                                 spill_after=c.spill_after)
+        self.served = self.kind.Served(cell, self.traffic)
+        self.svc = self.served.svc
         self.setup_times = {"traffic_s": t1 - t0,
                             "bank_s": time.perf_counter() - t1}
         self.flush_s = (None if c.flush_interval_ms is None
                         else c.flush_interval_ms / 1000.0)
         self.annotate = annotate or (lambda name: contextlib.nullcontext())
-        sizes = self.traffic.sizes
-        self.subscribed = [int(t) for t in np.argsort(
-            -sizes, kind="stable")[:cell.mix.topk_subscriptions]]
-        for t in self.subscribed:
-            self.svc.subscribe_topk(t, cell.mix.topk_m)
         # per tick since the service was built, what the driver handed
         # it: (its update operations in submission order, the tenants it
         # queried); an update is an op index of the traffic, or a
@@ -191,9 +198,9 @@ class Driver:
         """Compile every program shape the window uses; change no row.
 
         A zero-weight update ingests one all-padding block; one tick per
-        pad size runs the batched query; every tick refreshes the top-k
-        subscriptions; an idle tenant spills and a query re-admits it.
-        What it submits is logged like the window's operations.
+        pad size runs the batched query; the kind warms up its own
+        programs (``Served.warm_up``). What it submits is logged like the
+        window's operations.
         """
         import jax
 
@@ -217,12 +224,12 @@ class Driver:
                 svc.query(quiet, probe)
                 qs.append(quiet)
             tick()
-        if self.cell.config.spill_after is not None:
-            for _ in range(self.cell.config.spill_after + 1):
-                tick()
-            svc.query(quiet, zero)
-            qs.append(quiet)
-            tick()
+
+        def query(tenant, items):
+            svc.query(tenant, items)
+            qs.append(tenant)
+
+        self.served.warm_up(tick, query, quiet)
         jax.block_until_ready(svc.session.state)
 
     # -- the window --------------------------------------------------------
@@ -235,7 +242,6 @@ class Driver:
         svc.trace_admits = []
         self.first_tick = len(self.log)
         self.ticks: List[tuple] = []       # (b0, b1, a0, a1) of the trace
-        self.topk: Dict[int, list] = {}
         pend_upd: List[int] = []           # pending update ops
         pend_q: List[tuple] = []           # (op, ticket)
         oldest = [None]                    # due of the oldest pending op
@@ -275,10 +281,7 @@ class Driver:
                 acked[op] = True
                 win.query_lat.append(now - op_due[op])
                 tick_of_query[op] = (i, ticket.result())
-            for t in self.subscribed:
-                v = svc.topk_result(t)
-                if v is not None:
-                    self.topk.setdefault(t, []).append((i, v[0], v[1]))
+            self.served.after_tick(i)
             pend_upd.clear()
             pend_q.clear()
             pending[0] = 0
@@ -345,8 +348,25 @@ class Driver:
                 last = int(np.searchsorted(due, win.seconds, side="left"))
             return i, last
 
-        with self.annotate("chipbench.window"):
-            i, last = loop()
+        gc_start = [0]
+
+        def on_gc(phase, info):   # on a clock the window never reads
+            if phase == "start":
+                gc_start[0] = perf_counter_ns()
+            else:
+                win.gc_pauses.append((info["generation"], 1e-9 * (
+                    perf_counter_ns() - gc_start[0])))
+
+        ru0 = resource.getrusage(resource.RUSAGE_SELF)
+        gc.callbacks.append(on_gc)
+        try:
+            with self.annotate("chipbench.window"):
+                i, last = loop()
+        finally:
+            gc.callbacks.remove(on_gc)
+        ru1 = resource.getrusage(resource.RUSAGE_SELF)
+        win.page_faults = (ru1.ru_minflt - ru0.ru_minflt,
+                           ru1.ru_majflt - ru0.ru_majflt)
         win.stats1 = dict(svc.stats)
         win.behind_at_close = max(0, last - i)
         if watch is not None:
@@ -369,41 +389,6 @@ class Driver:
         win.unacknowledged = int((~acked[:min(last, n)]).sum())
         self.tick_of_query = tick_of_query
         return win
-
-    # -- what the check reads ---------------------------------------------
-
-    def record(self, sample, win: Window):
-        from chipbench.check import Record
-
-        tr, c = self.traffic, self.cell.config
-
-        def update(u):
-            if isinstance(u, tuple):
-                return u
-            s0, ln = tr.start[u], tr.length[u]
-            return (int(tr.tenant[u]), tr.keys[s0:s0 + ln],
-                    tr.weights[s0:s0 + ln])
-
-        queries = {}
-        for op, (tick_i, ans) in self.tick_of_query.items():
-            t = int(tr.tenant[op])
-            if t in sample:
-                s0, ln = tr.start[op], tr.length[op]
-                queries.setdefault(t, []).append(
-                    (tick_i, tr.keys[s0:s0 + ln], np.asarray(ans)))
-        rows = {t: self.svc.tenant_snapshot(t) for t in sample}
-        return Record(k=c.k_per_tenant, item_bits=c.bits, block=c.block,
-                      tenants=c.tenants, spill_after=c.spill_after,
-                      kept=list(self.subscribed),
-                      fed=[[update(u) for u in ups] for ups, _ in self.log],
-                      asked=[qs for _, qs in self.log],
-                      first_tick=self.first_tick,
-                      program_blocks=self.svc.trace_blocks,
-                      program_admits=self.svc.trace_admits,
-                      queries=queries,
-                      topk={t: v for t, v in self.topk.items()
-                            if t in sample},
-                      rows=rows, unacknowledged=win.unacknowledged)
 
 
 def settle_host() -> None:

@@ -1,6 +1,7 @@
 """The engine ingest's share of its roofline: the least bytes of the
-window's blocks (``chipbench.cost.block_least_bytes``: the rows each
-block reaches, read and written once, and the block itself) over the
+window's blocks (``least_bytes`` of the configuration's kind, under
+``chipbench/kinds/``: the rows each block reaches, read and written
+once, and the block itself) over the
 ingest's device time at the chip's HBM bandwidth, in percent. The
 ingest does no floating-point work, so bandwidth is its bound."""
 

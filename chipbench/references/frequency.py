@@ -35,7 +35,7 @@ I32 = 2**31 - 1
 
 
 def _sat(x):
-    return np.clip(x, -I32, I32)
+    return max(-I32, min(I32, int(x)))
 
 
 class Row:
@@ -62,7 +62,7 @@ class Row:
         np.add.at(net, inv, weights[live].astype(np.int64))
         keep = net != 0
         uids, net = uids[keep], net[keep]
-        slot = {int(x): j for j, x in enumerate(self.ids) if x >= 0}
+        slot = {x: j for j, x in enumerate(self.ids.tolist()) if x >= 0}
         inserts, w_del = [], 0
         for x, w in zip(uids.tolist(), net.tolist()):
             j = slot.get(x)
@@ -109,8 +109,10 @@ class Row:
         self.ids, self.counts, self.errors = ids, counts, errors
 
     def query(self, items: np.ndarray) -> np.ndarray:
-        slot = {int(x): c for x, c in zip(self.ids, self.counts) if x >= 0}
-        return np.array([slot.get(int(x), 0) for x in items], np.int64)
+        # a row monitors an item at most once
+        hit = (self.ids >= 0) & (self.ids == np.asarray(
+            items, np.int64)[:, None])
+        return (hit * self.counts).sum(axis=1)
 
     def topk(self, m: int):
         score = np.where(self.ids >= 0, self.counts, -2**31)

@@ -29,17 +29,16 @@ for _p in (ROOT, os.path.join(ROOT, "src")):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-TINY = {"tenants": 256, "k_per_tenant": 64, "block": 512}
-
 
 def tiny_cell(name: str, bench_path: str):
-    """The cell ``name`` at the rehearsal's size: bits kept (the key
-    shape), tenants, counters and block cut; an open mix slowed to fit
-    the CPU, a saturated one given small epochs."""
+    """The cell ``name`` at the rehearsal's size: the configuration cut
+    by its kind (``tiny``); an open mix slowed to fit the CPU, a
+    saturated one given small epochs."""
+    from chipbench import kinds
     from chipbench.harness import load_cell
 
     cell = load_cell(name, bench_path)
-    conf = dataclasses.replace(cell.config, **TINY)
+    conf = kinds.load(cell.config.kind).tiny(cell.config)
     mix = cell.mix
     if mix.arrival == "open":
         mix = dataclasses.replace(mix, rate=2000.0)
@@ -76,6 +75,7 @@ def aot() -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
+    from chipbench import kinds
     from chipbench.harness import Config
     from repro.sketch import api
     from repro.sketch.session import _ingest_fn
@@ -88,9 +88,7 @@ def aot() -> int:
         bench = json.load(f)
     for c in bench["configs"]:
         conf = Config.load(os.path.join(ROOT, c["file"]))
-        spec = api.SketchSpec(kind="frequency",
-                              k=conf.tenants * conf.k_per_tenant,
-                              bits=conf.bits, tenants=conf.tenants)
+        spec = kinds.load(conf.kind).spec(conf)
         state = jax.eval_shape(lambda: api.make(spec))
         state = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),

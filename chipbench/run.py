@@ -6,12 +6,12 @@
 
 Runs one cell of ``BENCHMARK.json`` on the chips of the machine it is
 started on (``chipbench/harness.py`` describes set-up and the window),
-checks what the timed path produced against the plain reference
-(``chipbench/check.py``), and prints, as the last line of standard
-output, one JSON object: ``correct``, ``attempted``, ``failed``,
-``metrics``, ``device`` (with ``--trace 1`` also ``breakdown``) and,
-last, ``checks``: each compared number beside its limit, which also end
-standard error.
+checks what the timed path produced against the plain reference of the
+configuration's kind (``chipbench/kinds/<kind>.py``), and prints, as
+the last line of standard output, one JSON object: ``correct``,
+``attempted``, ``failed``, ``metrics``, ``device`` (with ``--trace 1``
+also ``breakdown``) and, last, ``checks``: each compared number beside
+its limit, which also end standard error.
 
 ``--trace 0`` reports the cell's end-to-end metrics (host clock, tracing
 off). ``--trace 1`` runs the same window under the JAX profiler and
@@ -132,6 +132,26 @@ def percentile(xs, q: float) -> float:
     return float(np.percentile(np.asarray(xs, np.float64), q))
 
 
+def host_stalls(win) -> str:
+    """Where a slow window's time went on the host: the longest tick, the
+    longest gap between ticks, the driver's worst lag (each with when it
+    started, seconds into the window), the collector's pauses and the
+    process's page faults."""
+    spans = [(e - s, s) for s, e in win.ticks] or [(0.0, 0.0)]
+    gaps = [(s1 - e0, e0) for (_, e0), (s1, _) in zip(win.ticks,
+                                                       win.ticks[1:])]
+    tick, gap = max(spans), max(gaps or [(0.0, 0.0)])
+    lag = max(win.lags or [0.0])
+    pauses = [p for _, p in win.gc_pauses]
+    full = sum(1 for g, _ in win.gc_pauses if g == 2)
+    return (f"host: tick_max_ms={tick[0] * 1e3:.3f}@{tick[1]:.3f} "
+            f"gap_max_ms={gap[0] * 1e3:.3f}@{gap[1]:.3f} "
+            f"lag_max_ms={lag * 1e3:.3f} gc_n={len(pauses)} "
+            f"gc_gen2_n={full} gc_s={sum(pauses):.6f} "
+            f"gc_max_ms={max(pauses or [0.0]) * 1e3:.3f} "
+            f"minflt={win.page_faults[0]} majflt={win.page_faults[1]}")
+
+
 def execute(cell, seed: int, seconds: float, trace: bool, devices,
             bench: dict, device_metrics: bool = True,
             t_start: float = T_START, fault=None,
@@ -139,14 +159,14 @@ def execute(cell, seed: int, seconds: float, trace: bool, devices,
     """One run of ``cell``; returns the result object (see the module
     docstring). ``device_metrics=False`` (the CPU rehearsal) writes "not
     measured" for every device number. ``fault`` breaks the timed path
-    underneath (tests only). ``control`` also compares the control (see
-    ``chipbench.check``) and returns its numbers under
+    underneath (tests only). ``control`` also compares the kind's control
+    (see ``chipbench/kinds/``) and returns its numbers under
     ``control_checks``."""
     import gc
 
     import jax
 
-    from chipbench import check, cost
+    from chipbench import cost
     from chipbench import trace as tr
     from chipbench.harness import CompileWatch, Driver, settle_host
 
@@ -191,12 +211,12 @@ def execute(cell, seed: int, seconds: float, trace: bool, devices,
         f"admits={st1['admits'] - st0['admits']} "
         f"queries={len(win.query_lat)} wraps={win.wraps} "
         f"unacknowledged={win.unacknowledged}")
+    log(host_stalls(win))
 
     # the window's blocks: their least bytes, for the roofline
+    kind = drv.kind
     nb_window = sum(b1 - b0 for b0, b1, _, _ in drv.ticks[:len(win.ticks)])
-    least = [cost.block_least_bytes(ci, cw, cell.config.bits,
-                                     cell.config.k_per_tenant)
-             for ci, cw in drv.svc.trace_blocks[:nb_window]]
+    least = kind.least_bytes(cell.config, drv.svc.trace_blocks[:nb_window])
 
     summary = None
     if trace and device_metrics:
@@ -211,14 +231,14 @@ def execute(cell, seed: int, seconds: float, trace: bool, devices,
 
     # the check, once the window has closed and the peak was read
     t0 = time.perf_counter()
-    sample = check.draw_sample(drv.traffic.sizes,
-                               {t for _, t in drv.svc.trace_admits}, seed)
-    rec = drv.record(sample, win)
+    sample = kind.draw_sample(drv.traffic.sizes,
+                              {t for _, t in drv.svc.trace_admits}, seed)
+    rec = drv.served.record(drv, sample, win)
     del drv
     gc.collect()
-    res = check.compare(rec, sample)
+    res = kind.compare(rec, sample)
     nums = res["numbers"]
-    correct = check.verdict(nums)
+    correct = kind.verdict(nums)
     log(f"check: sample={sample} covered={res['covered']} "
         f"reference_s={time.perf_counter() - t0:.3f}")
 
@@ -257,10 +277,10 @@ def execute(cell, seed: int, seconds: float, trace: bool, devices,
             "device_ops": [[k, v] for k, v in summary.top_ops],
             "idle_gaps": [[k, v] for k, v in summary.idle_by_host]}
     if control:
-        out["control_checks"] = check.compare(rec, sample,
-                                              control=True)["numbers"]
-    out["checks"] = {k: {"value": nums[k], "limit": check.LIMITS[k]}
-                     for k in check.LIMITS}
+        out["control_checks"] = kind.compare(rec, sample,
+                                             control=True)["numbers"]
+    out["checks"] = {k: {"value": nums[k], "limit": kind.LIMITS[k]}
+                     for k in kind.LIMITS}
     return out
 
 

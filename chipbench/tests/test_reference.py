@@ -1,12 +1,12 @@
-"""The plain reference (``chipbench.reference``) states the semantics the
-program serves: on random blocks that fill rows, evict, and delete
-monitored and unmonitored items, every row the engine ingests equals the
-reference's, and so does a re-admitted row."""
+"""The plain frequency reference (``chipbench.references.frequency``)
+states the semantics the program serves: on random blocks that fill
+rows, evict, and delete monitored and unmonitored items, every row the
+engine ingests equals the reference's, and so does a re-admitted row."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench.reference import Row, exact_counts
+from chipbench.references.frequency import Row, exact_counts
 
 
 @pytest.mark.parametrize("k", [16, 128])

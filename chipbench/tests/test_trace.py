@@ -9,6 +9,7 @@ import pytest
 
 from chipbench import cost
 from chipbench import trace as tr
+from chipbench.kinds import frequency
 
 # device: ops [1,3) [2,4) [6,7) us inside a window [0,10) us; programs
 # ingest [1,4) and [6,7), query [6,7); host spans tick [0,5), wait [5,8)
@@ -111,8 +112,8 @@ def test_block_least_bytes():
     keys = np.array([(3 << bits) | 5, (3 << bits) | 9, (7 << bits) | 1,
                      (9 << bits) | 2, (11 << bits) | 4], np.int32)
     w = np.array([1, -1, 2, 0, 1], np.int32)     # tenant 9 only pads
-    assert cost.rows_reached(keys, w, bits) == 3
-    assert cost.block_least_bytes(keys, w, bits, k) == \
+    assert frequency.rows_reached(keys, w, bits) == 3
+    assert frequency.block_least_bytes(keys, w, bits, k) == \
         3 * 2048 * 3 * 4 * 2 + 5 * 8
 
 

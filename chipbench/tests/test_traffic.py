@@ -2,6 +2,7 @@
 the marginals of the generators it copies (``mixed_traffic`` and the
 CAIDA surrogate)."""
 import dataclasses
+import json
 import time
 
 import numpy as np
@@ -143,3 +144,87 @@ def test_caida_marginal_matches_the_surrogate():
     assert np.abs(fa[:32] - fb[:32]).max() < 0.005
     # the uniform background: the far tail's share
     assert fa[1024:].sum() == pytest.approx(fb[1024:].sum(), abs=0.005)
+
+
+def test_hot_tenants_take_all_traffic():
+    hot = dataclasses.replace(OPEN, hot_tenants=16)
+    a = generate(hot, 512, 2**31 + 21, 20.0)
+    b = generate(hot, 512, 2**31 + 22, 20.0)
+    for tr in (a, b):
+        assert (tr.sizes > 0).sum() == 16
+        assert set(np.unique(tr.tenant)) == set(np.flatnonzero(tr.sizes))
+    # the seed draws the hot set; the skew shares traffic among the 16
+    assert set(np.flatnonzero(a.sizes)) != set(np.flatnonzero(b.sizes))
+    assert np.array_equal(np.sort(a.sizes), np.sort(b.sizes))
+    ins = np.sort(a.sizes[a.sizes > 0])[::-1] / a.sizes.sum()
+    want = np.arange(1, 17) ** -1.2
+    assert np.abs(ins - want / want.sum()).max() < 0.01
+    flat = generate(dataclasses.replace(hot, tenant_skew=0.0), 512, 5, 20.0)
+    live = flat.sizes[flat.sizes > 0]
+    assert len(live) == 16 and live.max() - live.min() <= 2
+
+
+@pytest.mark.parametrize("mix", [OPEN, SAT], ids=["open", "saturated"])
+def test_targeted_deletes_least_frequent_first(mix):
+    from repro.core.streams import deletions_targeted
+
+    tr = generate(dataclasses.replace(mix, delete_pattern="targeted"), 128,
+                  2**31 + 23, 10.0)
+    ten, items, w = _updates(tr)
+    assert _min_running(ten, items, w) == 0
+    assert _min_running(np.r_[ten, ten], np.r_[items, items],
+                        np.r_[w, w]) == 0
+    # each tenant's (one epoch's) stream: inserts, then the deletions the
+    # paper's targeted rule picks from them, in its order
+    epoch = generate(dataclasses.replace(mix, delete_pattern="targeted",
+                                         epochs=1), 128, 2**31 + 23, 10.0)
+    ten, items, w = _updates(epoch)
+    for t in np.unique(ten)[:24]:
+        mine, sign = items[ten == t], w[ten == t]
+        n_ins = int((sign > 0).sum())
+        assert (sign[:n_ins] == 1).all() and (sign[n_ins:] == -1).all()
+        want = deletions_targeted(mine[:n_ins], n_ins // 2)
+        assert np.array_equal(mine[n_ins:], want)
+
+
+def test_bursty_arrivals_keep_the_rate():
+    even = generate(OPEN, 512, 2**31 + 25, 51.0)
+    for cv in (0.5, 2.0):
+        tr = generate(dataclasses.replace(OPEN, arrival_cv=cv), 512,
+                      2**31 + 25, 51.0)
+        assert np.array_equal(tr.keys, even.keys)
+        assert (np.diff(tr.due) >= 0).all() and tr.due[-1] < 51.0
+        # the window offers the same updates: the mean rate is kept
+        assert tr.n_updates == even.n_updates
+        u = np.flatnonzero(tr.kind == UPDATE)
+        gaps = np.diff(tr.due[u]) / tr.length[u][:-1]
+        assert gaps.std() / gaps.mean() == pytest.approx(cv, rel=0.15)
+
+
+def test_an_item_distribution_added_as_a_file(monkeypatch, tmp_path):
+    import sys
+    import types
+
+    toy = types.ModuleType("chipbench.dists.toy")
+    toy.sample = lambda rng, n, universe, skew: rng.integers(0, 4, n)
+    monkeypatch.setitem(sys.modules, "chipbench.dists.toy", toy)
+    path = tmp_path / "toy_items.json"
+    d = {k: v for k, v in dataclasses.asdict(OPEN).items() if k != "name"}
+    path.write_text(json.dumps(dict(d, item_dist="toy")))
+    mix = Mix.load(str(path))
+    tr = generate(mix, 64, 9, 5.0)
+    assert set(np.unique(tr.keys)) <= {0, 1, 2, 3}
+    path.write_text(json.dumps(dict(d, item_dist="no_such_dist")))
+    with pytest.raises(ValueError):
+        Mix.load(str(path))
+
+
+def test_unknown_mix_keys_are_refused(tmp_path):
+    d = {k: v for k, v in dataclasses.asdict(OPEN).items() if k != "name"}
+    path = tmp_path / "typo.json"
+    path.write_text(json.dumps(dict(d, hot_tenant=64)))   # hot_tenants
+    with pytest.raises(ValueError, match="hot_tenant"):
+        Mix.load(str(path))
+    path.write_text(json.dumps(dict(d, _note="underscored keys are notes")))
+    assert Mix.load(str(path)) == dataclasses.replace(OPEN, name="typo")
+

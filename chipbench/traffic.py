@@ -10,12 +10,19 @@ operations without a loop over tenants:
 * tenant sizes: inserts shared over tenants by weight
   ``rank^-tenant_skew`` (``mixed_traffic`` draws them multinomially;
   here they are the expected counts, the same set for every seed, and
-  the seed deals them out to tenant ids);
+  the seed deals them out to tenant ids). With ``hot_tenants`` N > 0,
+  all of them go to N tenants drawn by the seed, shared among those N
+  by the same rule, and every other tenant sends nothing;
 * each tenant's own stream: its inserts (items from ``item_dist`` over
   ``2^universe_bits``), then ``floor(delete_ratio * inserts)`` deletions
-  of a uniform subset of those inserts in random order — the paper's
-  bounded-deletion stream with ``order='inserts_first'`` (alpha =
-  1 / (1 - delete_ratio)), so no item's count ever goes negative;
+  of those inserts — the paper's bounded-deletion stream with
+  ``order='inserts_first'`` (alpha = 1 / (1 - delete_ratio)), so no
+  item's count ever goes negative. ``delete_pattern`` picks which:
+  ``random``, a uniform subset of the inserts in random order, or
+  ``targeted``, the tenant's least frequent items first, every copy of
+  one before the next (ties to the item inserted first) — the paper's
+  adversarial case (``core.streams.deletions_targeted`` with inserts
+  first, as ``benchmarks.common.adversarial_stream`` states it);
 * each tenant stream is cut into update operations of ``burst`` keys;
   after a share ``query_frac`` of them (drawn at random), a point query
   of ``query_keys`` items drawn (with replacement) from that burst;
@@ -25,21 +32,28 @@ operations without a loop over tenants:
 Item distributions: ``zipf`` (truncated Zipf of ``item_skew`` over the
 universe, as ``core.streams.zipf_insertions``) and ``caida`` (the
 CAIDA-2015 surrogate of ``core.streams.caida_like_insertions``: 90%
-Zipf(1.2), 10% uniform background).
+Zipf(1.2), 10% uniform background) are built in; any other name is the
+file ``chipbench/dists/<name>.py`` (its docstring says what it
+supplies).
 
 A traffic "epoch" is one such day of ``epoch_updates`` updates. An
 open-loop mix (``arrival: open``) offers updates at ``rate`` per second
 for the window, so its one epoch holds ``rate * seconds`` updates (less
 the deletions each tenant rounds down) and each operation is due when
-the updates before it would have arrived at an even pace. A
-saturated mix (``arrival: saturated``) has no due times: its epochs
-(``epochs`` of them, each from its own seed) are played back to back,
-and from the start again if a run ever reaches their end, which stays a
-valid bounded-deletion stream since every epoch is one.
+the updates before it would have arrived at an even pace. With
+``arrival_cv`` > 0 the gaps after each update operation are instead
+its even-pace gap times a Gamma draw of mean 1 and coefficient of
+variation ``arrival_cv``, scaled so that the window still offers
+``rate`` per second (bursty arrivals, as BurstGPT, arXiv:2401.17644,
+fits them). A saturated mix (``arrival: saturated``) has no due times:
+its epochs (``epochs`` of them, each from its own seed) are played back
+to back, and from the start again if a run ever reaches their end,
+which stays a valid bounded-deletion stream since every epoch is one.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import json
 import os
 from typing import Optional
@@ -47,6 +61,7 @@ from typing import Optional
 import numpy as np
 
 UPDATE, QUERY = 0, 1
+BUILT_IN_DISTS = ("zipf", "caida")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,6 +81,9 @@ class Mix:
     epochs: int = 1              # saturated: epochs generated in set-up
     topk_subscriptions: int = 0  # top-k subscribed on the largest tenants
     topk_m: int = 16
+    hot_tenants: int = 0         # > 0: all traffic on that many tenants
+    delete_pattern: str = "random"   # "random" | "targeted"
+    arrival_cv: float = 0.0      # open loop: CV of the gaps (0: even pace)
 
     @staticmethod
     def load(path: str) -> "Mix":
@@ -73,12 +91,31 @@ class Mix:
             d = json.load(f)
         d = {k: v for k, v in d.items() if not k.startswith("_")}
         d.setdefault("name", os.path.splitext(os.path.basename(path))[0])
+        unknown = set(d) - {f.name for f in dataclasses.fields(Mix)}
+        if unknown:
+            raise ValueError(f"{path}: unknown keys {sorted(unknown)}")
         mix = Mix(**d)
         if mix.arrival not in ("open", "saturated"):
             raise ValueError(f"{path}: arrival must be open or saturated")
-        if mix.item_dist not in ("zipf", "caida"):
-            raise ValueError(f"{path}: item_dist must be zipf or caida")
+        if mix.delete_pattern not in ("random", "targeted"):
+            raise ValueError(f"{path}: delete_pattern must be random or "
+                             f"targeted")
+        if mix.hot_tenants < 0 or mix.arrival_cv < 0:
+            raise ValueError(f"{path}: hot_tenants and arrival_cv must "
+                             f"not be negative")
+        if mix.item_dist not in BUILT_IN_DISTS:
+            try:
+                dist_module(mix.item_dist)
+            except ModuleNotFoundError as e:
+                raise ValueError(f"{path}: item_dist {mix.item_dist!r} is "
+                                 f"neither built in {BUILT_IN_DISTS} nor "
+                                 f"chipbench/dists/{mix.item_dist}.py") from e
         return mix
+
+
+def dist_module(name: str):
+    """The item distribution ``chipbench/dists/<name>.py``."""
+    return importlib.import_module(f"chipbench.dists.{name}")
 
 
 @dataclasses.dataclass
@@ -126,6 +163,9 @@ def sample_items(rng: np.random.Generator, dist: str, n: int, universe: int,
 
     if dist == "zipf":
         return zipf(n, skew).astype(np.int32)
+    if dist not in BUILT_IN_DISTS:
+        return np.asarray(dist_module(dist).sample(rng, n, universe, skew),
+                          np.int32)
     # caida: 90% Zipf(1.2) body, 10% uniform background, mixed per draw
     body = rng.random(n) < 0.9
     out = rng.integers(0, universe, size=n)
@@ -151,32 +191,43 @@ def tenant_day(rng: np.random.Generator, mix: Mix, num_tenants: int,
     with operations in global order and ``sizes`` per tenant."""
     universe = 1 << mix.universe_bits
     n_ins = int(round(n_updates / (1.0 + mix.delete_ratio)))
-    ins_t = rng.permutation(tenant_sizes(n_ins, num_tenants,
-                                         mix.tenant_skew))
+    if mix.hot_tenants:
+        hot = rng.choice(num_tenants, mix.hot_tenants, replace=False)
+        ins_t = np.zeros(num_tenants, np.int64)
+        ins_t[hot] = rng.permutation(tenant_sizes(n_ins, mix.hot_tenants,
+                                                  mix.tenant_skew))
+    else:
+        ins_t = rng.permutation(tenant_sizes(n_ins, num_tenants,
+                                             mix.tenant_skew))
     del_t = np.floor(mix.delete_ratio * ins_t).astype(np.int64)
     ins_tenant = np.repeat(np.arange(num_tenants), ins_t)
     ins_items = sample_items(rng, mix.item_dist, n_ins, universe,
                              mix.item_skew)
 
-    # deletions: a uniform subset of each tenant's inserts, random order
     first_ins = np.concatenate([[0], np.cumsum(ins_t)[:-1]])
-    order = np.lexsort((rng.random(n_ins), ins_tenant))
-    rank = np.arange(n_ins) - first_ins[ins_tenant[order]]
-    dels = order[rank < del_t[ins_tenant[order]]]   # tenant-major, random
+    if mix.delete_pattern == "targeted":
+        del_items = targeted_deletions(ins_tenant, ins_items, first_ins,
+                                       del_t, mix.universe_bits)
+    else:
+        # a uniform subset of each tenant's inserts, random order
+        order = np.lexsort((rng.random(n_ins), ins_tenant))
+        rank = np.arange(n_ins) - first_ins[ins_tenant[order]]
+        dels = order[rank < del_t[ins_tenant[order]]]  # tenant-major
+        del_items = ins_items[dels]
     # each tenant's stream: its inserts, then its deletions
     len_t = ins_t + del_t
     first = np.concatenate([[0], np.cumsum(len_t)[:-1]])
     pos_ins = first[ins_tenant] + (np.arange(n_ins) - first_ins[ins_tenant])
-    del_tenant = ins_tenant[dels]
+    del_tenant = np.repeat(np.arange(num_tenants), del_t)
     first_del = np.concatenate([[0], np.cumsum(del_t)[:-1]])
     pos_del = first[del_tenant] + ins_t[del_tenant] + (
-        np.arange(len(dels)) - first_del[del_tenant])
+        np.arange(len(del_items)) - first_del[del_tenant])
     total = int(len_t.sum())
     s_items = np.empty(total, np.int32)
     s_w = np.empty(total, np.int32)
     s_items[pos_ins] = ins_items
     s_w[pos_ins] = 1
-    s_items[pos_del] = ins_items[dels]
+    s_items[pos_del] = del_items
     s_w[pos_del] = -1
 
     # update operations: bursts of each tenant's stream, tenant-major
@@ -223,6 +274,23 @@ def tenant_day(rng: np.random.Generator, mix: Mix, num_tenants: int,
             len_t)
 
 
+def targeted_deletions(ins_tenant: np.ndarray, ins_items: np.ndarray,
+                       first_ins: np.ndarray, del_t: np.ndarray,
+                       item_bits: int) -> np.ndarray:
+    """Each tenant's ``del_t`` deletions, tenant-major: its least
+    frequent items first, every copy of one before the next, ties to the
+    item inserted first (``core.streams.deletions_targeted`` per tenant,
+    over inserts sorted by tenant)."""
+    key = (ins_tenant.astype(np.int64) << item_bits) | ins_items
+    uk, first, cnt = np.unique(key, return_index=True, return_counts=True)
+    ut = uk >> item_bits
+    order = np.lexsort((first, cnt, ut))
+    uk, ut, cnt = uk[order], ut[order], cnt[order]
+    before = np.cumsum(cnt) - cnt - first_ins[ut]   # copies ahead, per tenant
+    take = np.clip(del_t[ut] - before, 0, cnt)
+    return np.repeat(uk & ((1 << item_bits) - 1), take).astype(np.int32)
+
+
 def generate(mix: Mix, num_tenants: int, seed: int,
              seconds: float) -> Traffic:
     """The operations of one run of ``mix`` over ``num_tenants`` tenants."""
@@ -247,7 +315,13 @@ def generate(mix: Mix, num_tenants: int, seed: int,
         # spread evenly over the window: each tenant's deletions round
         # down, so a day holds slightly fewer than rate * seconds updates
         upd = np.where(kind == UPDATE, length, 0).astype(np.float64)
-        due = (np.cumsum(upd) - upd) * (seconds / upd.sum())
+        if mix.arrival_cv > 0:
+            shape = mix.arrival_cv ** -2.0
+            rng = np.random.default_rng(np.random.SeedSequence(
+                [int(seed), 0xB0257]))
+            upd = upd * rng.gamma(shape, 1.0 / shape, len(upd))
+        due = np.concatenate([[0.0], np.cumsum(upd)[:-1]]) * (
+            seconds / upd.sum())
     return Traffic(kind=kind, tenant=cat(1), start=cat(2), length=length,
                    keys=cat(4), weights=cat(5), due=due,
                    sizes=np.sum([p[6] for p in parts], axis=0))
