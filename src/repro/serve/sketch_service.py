@@ -25,9 +25,11 @@ step, and every stage is batched across tenants:
      owner-row gather (``api.query_many``), then slice back per ticket;
   4. **subscriptions** — due continuous top-k subscriptions answer in
      ONE batched row gather (``tenant.topk_tenants``) when the layout
-     allows (base axis, uniform m), else per tenant; quantile
-     subscriptions run the per-tenant lockstep search
-     (``tenant.tenant_quantile_many``) on a composite-key dyadic bank;
+     allows (base axis, uniform m), else per tenant; due quantile
+     subscriptions of the per-tenant dyadic layout answer in ONE
+     lockstep search over every (tenant, q) lane
+     (``tenant.tenant_quantiles``), those of the composite-key dyadic
+     bank per tenant (``tenant.tenant_quantile_many``);
   5. **eviction** — tenants idle for ``spill_after`` ticks (no traffic,
      no subscription) spill their rows to tagged numpy dicts, all in
      one device read (``tenant.spill_tenants``), and their rows clear
@@ -38,16 +40,19 @@ every update submitted before it is visible to every query answered by
 it, exactly once (the feeder flush joins the device).
 
 Telemetry: each stage that has work runs inside a span — ``admit``,
-``ingest``, ``query``, ``subscriptions``, ``spill`` (every tick while
-spill is on: its scan for idle tenants is work) — and every point
-where the tick blocks on the device (the feeder's waits, the query and
-top-k reads, the spill read) inside a nested ``wait`` span. A span is
+``ingest``, ``query``, ``subscriptions`` (with the quantile search in a
+nested ``quantiles`` span), ``spill`` (every tick while spill is on: its
+scan for idle tenants is work) — and every point where the tick blocks
+on the device (the feeder's waits, the query, top-k and quantile reads,
+the spill read) inside a nested ``wait`` span. A span is
 a ``jax.profiler.TraceAnnotation`` named ``sketch.<name>`` carrying
 ``tick=<n>``, so it lands on a profiler trace's host plane, on the
 device's clock; it also adds its nanoseconds and a count to
 ``stats['<name>_ns']`` and ``stats['<name>_n']``. ``wait_n`` is thus
 the tick's host syncs. ``stats['ingest_chunks']`` counts the trips of
-the ingest's chunk loop the fed blocks take (``bank.ingest_chunks``).
+the ingest's chunk loop the fed blocks take (``bank.ingest_chunks``),
+and, on the per-tenant dyadic layout, ``stats['level_rows']`` the layer
+rows the blocks reach (``bits`` for each tenant a block reaches).
 Every value in ``stats`` is a flat int, so ``dict(svc.stats)`` is a
 snapshot.
 
@@ -78,7 +83,8 @@ from repro.sketch.session import BlockFeeder, StreamSession
 _MIN_QUERY_PAD = 128
 
 # the tick's spans (see the module docstring), each with its stats keys
-_SPANS = ("admit", "ingest", "query", "subscriptions", "spill", "wait")
+_SPANS = ("admit", "ingest", "query", "subscriptions", "quantiles", "spill",
+          "wait")
 
 
 class _Span:
@@ -151,8 +157,11 @@ class SketchService:
     Frequency mode (``spec.tenants`` set): per-tenant counts / top-k on
     the (T*S, k) tenant bank, any registered variant (sspm / lazy /
     double / unbiased). Quantile mode (``spec.kind == 'quantile'``):
-    pass ``tenant_bits`` — the composite-key dyadic layout; per-tenant
-    quantile subscriptions, no top-k, no spill.
+    with ``spec.tenants`` set, the per-tenant dyadic layout — each
+    tenant's own ``bits`` layer rows (``tenant.TenantLevels``), so each
+    tenant's rank error is bounded by its own mass; without it, pass
+    ``tenant_bits`` — one dyadic bank over composite keys. Either way:
+    per-tenant quantile subscriptions, no top-k, no spill.
 
     ``window``: per-tenant bounded-deletion horizon in TICKS — after
     ``window`` further ticks with traffic from tenant t, a tick's batch
@@ -165,12 +174,24 @@ class SketchService:
                  window: Optional[int] = None, depth: int = 2,
                  spill_after: Optional[int] = None,
                  tenant_bits: Optional[int] = None, donate: bool = True):
-        if spec.kind == "quantile":
+        if spec.kind == "quantile" and spec.tenants is not None:
+            if tenant_bits is not None:
+                raise ValueError(
+                    "tenant_bits is the composite-key quantile mode; a "
+                    "spec with tenants= carries its own tenant layout")
+            if spill_after is not None:
+                raise ValueError(
+                    "spill is refused in quantile mode: use "
+                    "spill_after=None")
+            self.num_tenants = spec.tenants
+            self.item_bits = spec.bits
+        elif spec.kind == "quantile":
             if tenant_bits is None:
                 raise ValueError(
-                    "quantile-mode service needs tenant_bits: the dyadic "
-                    "spec has no tenants axis, so the key split "
-                    "(tenant_bits high | item_bits low) must be given")
+                    "quantile-mode service needs tenants= in the spec "
+                    "(the per-tenant dyadic layout) or tenant_bits (one "
+                    "dyadic bank over composite keys: tenant_bits high "
+                    "| item_bits low)")
             if spec.shards is not None:
                 raise ValueError(
                     "quantile-mode service supports unsharded dyadic "
@@ -200,7 +221,8 @@ class SketchService:
         self.session = StreamSession(spec, block=block, window=window,
                                      donate=donate)
         self.stats = {"updates": 0, "queries": 0, "ticks": 0, "blocks": 0,
-                      "spills": 0, "admits": 0, "ingest_chunks": 0}
+                      "spills": 0, "admits": 0, "ingest_chunks": 0,
+                      "level_rows": 0}
         for name in _SPANS:
             self.stats[f"{name}_ns"] = self.stats[f"{name}_n"] = 0
         self._wait = functools.partial(self._span, "wait")
@@ -229,10 +251,14 @@ class SketchService:
             = None
         self.trace_admits: List[Tuple[int, int]] = []
         # the router the engine ingests with, for the chunk count
-        self._router = (tn.router_for(self.num_tenants, self.item_bits,
-                                      spec.shards or 1)
-                        if isinstance(self.session.state, tn.TenantBank)
-                        else None)
+        self._levels = isinstance(self.session.state, tn.TenantLevels)
+        if self._levels:
+            self._router = bk.TenantLevelRouter(self.num_tenants, spec.bits)
+        elif isinstance(self.session.state, tn.TenantBank):
+            self._router = tn.router_for(self.num_tenants, self.item_bits,
+                                         spec.shards or 1)
+        else:
+            self._router = None
 
     def _spillable(self) -> bool:
         return isinstance(self.session.state, tn.TenantBank)
@@ -307,7 +333,8 @@ class SketchService:
         if self.spec.kind != "quantile":
             raise ValueError(
                 "quantile subscriptions need a quantile-mode service "
-                "(SketchSpec(kind='quantile') + tenant_bits)")
+                "(SketchSpec(kind='quantile', tenants=T), or a quantile "
+                "spec + tenant_bits)")
         tenant = self._check_tenant(tenant)
         self._quant_subs[tenant] = {
             "qs": np.asarray(qs, np.float32).ravel(),
@@ -410,25 +437,35 @@ class SketchService:
                 self.trace_blocks.append((ci.copy(), cw.copy()))
             self.feeder.feed(ci, cw)
             self.stats["blocks"] += 1
-        self.stats["ingest_chunks"] += self._chunks(np.asarray(ends), B)
+        self._count_reach(np.asarray(ends), B)
         self.feeder.flush()  # the tick's consistency barrier
 
-    def _chunks(self, ends: np.ndarray, B: int) -> int:
-        """Trips of the ingest's chunk loop over this tick's blocks.
+    def _count_reach(self, ends: np.ndarray, B: int) -> None:
+        """Count the trips of the ingest's chunk loop over this tick's
+        blocks (``ingest_chunks``) and the layer rows they reach
+        (``level_rows``).
 
-        One tenant owns one row on the chunked path, and ``ends`` closes
-        each tenant's run of the coalesced stream, so a block reaches the
-        runs that overlap it: those starting before its end, less those
-        ending at or before its start. A run counts as reached whatever
-        its weights."""
+        ``ends`` closes each tenant's run of the coalesced stream, so a
+        block reaches the runs that overlap it: those starting before its
+        end, less those ending at or before its start. A run counts as
+        reached whatever its weights. A tenant owns one row on the
+        chunked path, ``bits`` rows on the per-tenant dyadic layout,
+        whose blocks expand ``bits``-fold on the device."""
         lo = np.arange(0, int(ends[-1]), B)   # each block's start
         if self._router is None:
-            return len(lo)
+            self.stats["ingest_chunks"] += len(lo)
+            return
         starts = np.r_[0, ends[:-1]]
         reached = (np.searchsorted(starts, lo + B, side="left")
                    - np.searchsorted(ends, lo, side="right"))
         k = self.session.state.bank.ids.shape[1]
-        return int(bk.ingest_chunks(self._router, B, k, reached).sum())
+        router = self._router
+        if self._levels:
+            reached = reached * router.bits
+            self.stats["level_rows"] += int(reached.sum())
+            router, B = router.rows, B * router.bits
+        self.stats["ingest_chunks"] += int(
+            bk.ingest_chunks(router, B, k, reached).sum())
 
     def _refresh_subscriptions(self) -> None:
         due_topk = [t for t, s in self._topk_subs.items()
@@ -461,15 +498,45 @@ class SketchService:
             for t in due_topk:
                 self._topk_subs[t]["due"] = self._tick \
                     + self._topk_subs[t]["every"]
-        for t, sub in self._quant_subs.items():
-            if self._tick < sub["due"]:
-                continue
-            qv = tn.tenant_quantile_many(
-                self.session.state, t, jnp.asarray(sub["qs"]),
-                self.item_bits)
-            with self._wait():
-                sub["value"] = np.asarray(qv)
-            sub["due"] = self._tick + sub["every"]
+        due_q = [t for t, s in self._quant_subs.items()
+                 if self._tick >= s["due"]]
+        if not due_q:
+            return
+        with self._span("quantiles"):
+            if self._levels:
+                qs = [self._quant_subs[t]["qs"] for t in due_q]
+                for t, v in zip(due_q, self._level_quantiles(due_q, qs)):
+                    self._quant_subs[t]["value"] = v
+            else:
+                for t in due_q:
+                    qv = tn.tenant_quantile_many(
+                        self.session.state, t,
+                        jnp.asarray(self._quant_subs[t]["qs"]),
+                        self.item_bits)
+                    with self._wait():
+                        self._quant_subs[t]["value"] = np.asarray(qv)
+        for t in due_q:
+            self._quant_subs[t]["due"] = self._tick \
+                + self._quant_subs[t]["every"]
+
+    def _level_quantiles(self, tenants: List[int],
+                         qs: List[np.ndarray]) -> List[np.ndarray]:
+        """Every tenant's quantiles in ONE search and ONE read: the
+        tenants padded to a power of two and each q list to the longest
+        (repeating its last q), so a day of ticks compiles the search a
+        handful of times."""
+        n, m = len(tenants), max(len(q) for q in qs)
+        n_pad = 1 << (n - 1).bit_length()
+        t_pad = np.zeros(n_pad, np.int32)
+        t_pad[:n] = tenants
+        q_pad = np.zeros((n_pad, m), np.float32)
+        for i, q in enumerate(qs):
+            q_pad[i] = np.pad(q, (0, m - len(q)), mode="edge")
+        ans = tn.tenant_quantiles(self.session.state, jnp.asarray(t_pad),
+                                  jnp.asarray(q_pad))
+        with self._wait():
+            ans = np.asarray(ans)
+        return [ans[i, :len(q)] for i, q in enumerate(qs)]
 
     def _spill_idle(self) -> None:
         keep = set(self._topk_subs) | set(self._quant_subs) \
@@ -502,10 +569,19 @@ class SketchService:
 
     def tenant_snapshot(self, tenant: int) -> Dict[str, Any]:
         """A tenant's counters in the spill format (``tenant.spill_rows``)
-        as they stand, whether resident or spilled; evicts nothing."""
+        as they stand, whether resident or spilled; evicts nothing. On
+        the per-tenant dyadic layout: its ``bits`` layer rows and
+        ``mass``."""
         tenant = self._check_tenant(tenant)
         if tenant in self._spilled:
             return dict(self._spilled[tenant])
+        if self._levels:
+            rows = tn.extract_rows(self.session.state.bank,
+                                   tn.tenant_rows(tenant, self.spec.bits))
+            return {"ids": np.asarray(rows.ids),
+                    "counts": np.asarray(rows.counts),
+                    "errors": np.asarray(rows.errors),
+                    "mass": int(self.session.state.mass[tenant])}
         return tn.spill_rows(self.session.state.bank, tenant,
                              self.spec.shards or 1, self.item_bits)
 
@@ -528,10 +604,13 @@ class SketchService:
         """Current per-tenant quantiles (quantile mode); settles first."""
         tenant = self._check_tenant(tenant)
         self._settle(tenant)
+        qs = np.asarray(qs, np.float32).ravel()
+        if self._levels:
+            return np.asarray(tn.tenant_quantiles(
+                self.session.state, jnp.asarray([tenant], jnp.int32),
+                jnp.asarray(qs[None])))[0]
         return np.asarray(tn.tenant_quantile_many(
-            self.session.state, tenant,
-            jnp.asarray(np.asarray(qs, np.float32).ravel()),
-            self.item_bits))
+            self.session.state, tenant, jnp.asarray(qs), self.item_bits))
 
     # -- crash / resume ----------------------------------------------------
 

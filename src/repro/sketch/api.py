@@ -113,7 +113,12 @@ class SketchSpec:
     the per-tenant item-universe bound composite keys are packed
     against). Size with ``k``/``eps`` (split evenly across tenants) or
     ``tenant_caps`` (one capacity per tenant — per-tenant BLOCKED
-    masks; base variants only).
+    masks; base variants only). With ``kind='quantile'`` each tenant
+    owns ``bits`` rows, one per dyadic layer, sized per tenant by
+    ``eps`` (or ``k`` split evenly across tenants), so every tenant's
+    rank error is bounded by its own mass (``repro.sketch.tenant``'s
+    per-tenant Dyadic SS± layout); its row-tagged keys need
+    ``bits`` + the bits of ``tenants·bits`` rows <= 31.
     """
 
     kind: str = "frequency"
@@ -173,11 +178,6 @@ class SketchSpec:
             if self.tenants < 1:
                 raise ValueError(
                     f"tenants must be >= 1 or None, got {self.tenants}")
-            if self.kind != "frequency":
-                raise ValueError(
-                    "tenants=T is a frequency-kind layout; per-tenant "
-                    "quantiles run a plain quantile spec over composite "
-                    "keys instead (repro.sketch.tenant.tenant_rank_many)")
             if self.bits is None:
                 raise ValueError(
                     "tenants=T needs bits (the per-tenant item-universe "
@@ -189,6 +189,21 @@ class SketchSpec:
                     f"composite keys need tenant_bits + bits <= 31 to fit "
                     f"the int32 id dtype; got tenants={self.tenants} "
                     f"({tb} bits) with bits={self.bits}")
+            if self.kind == "quantile":
+                rb = (self.tenants * self.bits - 1).bit_length()
+                if rb + self.bits > 31:
+                    raise ValueError(
+                        f"per-tenant dyadic rows tag their keys with the "
+                        f"row (tenant*bits + layer), so row_bits + bits "
+                        f"<= 31 must fit the int32 id dtype; got "
+                        f"tenants={self.tenants} x bits={self.bits} rows "
+                        f"({rb} bits) with bits={self.bits}")
+                if self.tenant_caps is not None or self.shards is not None:
+                    raise ValueError(
+                        "the per-tenant quantile layout is one row per "
+                        "(tenant, dyadic layer), sized by eps or k: "
+                        "tenant_caps and shards are frequency-kind "
+                        "layouts")
             if self.tenant_caps is not None:
                 if len(self.tenant_caps) != self.tenants:
                     raise ValueError(
@@ -233,11 +248,15 @@ class SketchSpec:
                             "lazy" if self.variant == "lazy" else "ss_pm")
 
     def layer_capacities(self) -> list:
-        """Per-layer counters of one quantile summary (shared helper)."""
+        """Per-layer counters of one quantile summary (shared helper) —
+        one tenant's, where ``k`` splits evenly across ``tenants``."""
         if self.kind != "quantile":
             raise ValueError("layer_capacities() applies to quantile kinds")
+        k = self.k
+        if k is not None and self.tenants:
+            k = -(-k // self.tenants)
         return dyadic_layer_capacities(
-            self.bits, total_counters=self.k, eps=self.eps, alpha=self.alpha)
+            self.bits, total_counters=k, eps=self.eps, alpha=self.alpha)
 
 
 def backends_for(kind: str, shards: Optional[int], variant: str = "sspm",
@@ -250,12 +269,12 @@ def backends_for(kind: str, shards: Optional[int], variant: str = "sspm",
     CR-precis layout is reachable as ``backend='crprecis'`` on unsharded
     sspm frequency specs (it is a different summary, not an execution
     path of the SpaceSaving± store — sharding it would break its linear
-    row arithmetic for no space gain). Multi-tenant layouts are
-    frequency-kind fused-engine banks only (their whole point is the
+    row arithmetic for no space gain). Multi-tenant layouts, of either
+    kind, are fused-engine banks only (their whole point is the
     one-launch routed ingest).
     """
     if tenants:
-        return ("bank",) if kind == "frequency" else ()
+        return ("bank",)
     if variant in FAMILY_VARIANTS:
         return ("bank",) if kind == "frequency" else ()
     if kind == "quantile" and shards:
@@ -372,7 +391,7 @@ def validate_block(spec: SketchSpec, items, weights, *,
                 f"cross int32 max ({int32_max}) mid-merge (adds "
                 f"saturate, losing mass). Split the block, rescale "
                 f"weights, or checkpoint-and-reset the session.")
-    if spec.kind == "quantile":
+    if spec.kind == "quantile" and spec.tenants is None:
         hi = 1 << spec.bits
         if (i[real] >= hi).any():
             bad = int(i[real][i[real] >= hi][0])
@@ -685,6 +704,8 @@ from . import tenant as _tenant  # noqa: E402
 
 register_adapter("frequency", False, _tenant.TenantAdapter(), tenants=True)
 register_adapter("frequency", True, _tenant.TenantAdapter(), tenants=True)
+register_adapter("quantile", False, _tenant.TenantLevelsAdapter(),
+                 tenants=True)
 for _sharded in (False, True):
     register_adapter("frequency", _sharded, _family.DoubleAdapter(),
                      axis="double", tenants=True)
@@ -840,7 +861,7 @@ def infer_spec(spec: SketchSpec, d: Dict[str, Any]) -> SketchSpec:
         changes["shards"] = shards
     raw_tenants = d.get("tenants")
     n_tenants = int(np.asarray(raw_tenants)) if raw_tenants is not None else 0
-    tenants = (n_tenants or None) if kind == "frequency" else None
+    tenants = n_tenants or None
     if tenants != spec.tenants:
         changes["tenants"] = tenants
         if spec.tenant_caps is not None:
@@ -934,10 +955,11 @@ def _validate_checkpoint(spec: SketchSpec, d: Dict[str, Any]) -> None:
                 f"dict is truncated or mixes two checkpoints")
     if spec.kind == "quantile":
         mass = np.asarray(d["mass"])
-        if mass.dtype.kind not in "iu" or mass.size != 1:
+        if mass.dtype.kind not in "iu" or mass.size != (spec.tenants or 1):
             raise ValueError(
-                f"checkpoint field 'mass' must be an integer scalar "
-                f"(|F|₁), got dtype {mass.dtype}, shape {mass.shape}")
+                f"checkpoint field 'mass' must hold one integer |F|₁ per "
+                f"tenant ({spec.tenants or 1}), got dtype {mass.dtype}, "
+                f"shape {mass.shape}")
 
 
 def restore(spec: SketchSpec, d: Dict[str, Any]):

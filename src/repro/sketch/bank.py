@@ -18,7 +18,11 @@ padding), and a pluggable **router** deciding what a row means —
   * :class:`TenantRouter`      rows are tenants (× per-tenant hash
     shards); composite keys (tenant << item_bits) | item route to the
     owning tenant's rows only — the multi-tenant service bank
-    (``repro.sketch.tenant``).
+    (``repro.sketch.tenant``);
+  * :class:`TenantLevelRouter` rows are (tenant, dyadic layer) pairs;
+    a composite key expands into one row-tagged key per layer, a
+    ``TenantRouter`` block over those rows — the per-tenant Dyadic
+    SS± bank (``repro.sketch.tenant``).
 
 Routers are frozen dataclasses (hashable → jit-static) with two duties:
 ``route_dense(items, weights) -> (R, B) row-sorted views`` and, for
@@ -92,11 +96,12 @@ def init(capacities: Union[int, Sequence[int]],
         caps = [int(c) for c in capacities]
         assert num_rows is None or num_rows == len(caps)
     k = max(caps)
-    lane = np.arange(k)[None, :]
-    real = lane < np.asarray(caps)[:, None]  # (R, k) live-slot mask
+    # built on the device: only the R capacities cross from the host
+    lane = jnp.arange(k, dtype=jnp.int32)[None, :]
+    real = lane < jnp.asarray(caps, jnp.int32)[:, None]  # (R, k) live slots
     return SketchState(
-        ids=jnp.asarray(np.where(real, int(EMPTY), int(BLOCKED)), jnp.int32),
-        counts=jnp.asarray(np.where(real, 0, int(_INT_MAX)), jnp.int32),
+        ids=jnp.where(real, EMPTY, BLOCKED),
+        counts=jnp.where(real, jnp.int32(0), _INT_MAX),
         errors=jnp.zeros((len(caps), k), jnp.int32),
     )
 
@@ -204,11 +209,11 @@ class TenantRouter:
 
     Composite keys from different tenants never collide, so ownership —
     and therefore monitoring, queries and top-k — never crosses a tenant
-    boundary: isolation is routing, not bookkeeping. Composes with the
-    dyadic layout the way ``ShardLevelRouter`` composes shard × level: a
-    dyadic bank over composite keys answers per-tenant ranks/quantiles
-    as range differences inside the tenant's key range
-    (``repro.sketch.tenant.tenant_rank_many``).
+    boundary: isolation is routing, not bookkeeping. Quantiles get rows
+    of their own the same way: :class:`TenantLevelRouter` gives each
+    tenant one row per dyadic layer and feeds them as a block of this
+    router over those rows, so each tenant keeps the paper's rank bound
+    over its own stream.
     """
 
     num_tenants: int
@@ -254,6 +259,46 @@ class TenantRouter:
                     weights: jax.Array) -> Tuple[jax.Array, jax.Array]:
         """(B,) block -> (T*S, B): sorted block broadcast, foreign 0."""
         return _partition_route_dense(self, items, weights)
+
+
+@dataclasses.dataclass(frozen=True)
+class TenantLevelRouter:
+    """Per-tenant dyadic layers: tenant t's layer l is row ``t*bits + l``.
+
+    A composite key ``(t << bits) | x`` expands, on the device, into one
+    row-tagged key per layer, ``((t*bits + l) << bits) | (x >> l)``, its
+    weight repeated (``expand``). The expanded block is a block of the
+    partition router ``rows`` — a :class:`TenantRouter` over the
+    ``T*bits`` rows with ``bits`` item bits — whose owner is monotone in
+    key order, so it takes the same ingest as any tenant bank
+    (``update_block_fused``; the touched-rows path where
+    ``takes_touched(rows, bits*B, k)`` holds). Each row sees exactly the
+    layer-l node stream of its own tenant, as the tenant's own
+    ``DyadicLevelRouter`` bank would.
+    """
+
+    num_tenants: int
+    bits: int
+
+    @property
+    def rows(self) -> TenantRouter:
+        return TenantRouter(self.num_tenants * self.bits, self.bits)
+
+    def key(self, row: jax.Array, node: jax.Array) -> jax.Array:
+        """The id under which ``row`` monitors ``node``."""
+        return jnp.left_shift(row, self.bits) | node
+
+    def expand(self, keys: jax.Array,
+               weights: jax.Array) -> Tuple[jax.Array, jax.Array]:
+        """(B,) composite keys -> (bits*B,) row-tagged keys, layer-major."""
+        keys = keys.astype(jnp.int32)
+        lvl = jnp.arange(self.bits, dtype=jnp.int32)[:, None]
+        row = jnp.right_shift(keys, self.bits)[None, :] * self.bits + lvl
+        node = jnp.right_shift(
+            jnp.bitwise_and(keys, (1 << self.bits) - 1)[None, :], lvl)
+        tagged = self.key(row, node)
+        w = jnp.broadcast_to(weights.astype(jnp.int32)[None, :], tagged.shape)
+        return tagged.reshape(-1), w.reshape(-1)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -327,7 +372,7 @@ class ShardLevelRouter:
 
 
 Router = Union[HashShardRouter, TenantRouter, DyadicLevelRouter,
-               ShardLevelRouter]
+               ShardLevelRouter]  # TenantLevelRouter feeds a TenantRouter
 
 
 # ---------------------------------------------------------------------------
@@ -1068,6 +1113,7 @@ __all__ = [
     "sort_block",
     "HashShardRouter",
     "TenantRouter",
+    "TenantLevelRouter",
     "DyadicLevelRouter",
     "ShardLevelRouter",
     "Router",

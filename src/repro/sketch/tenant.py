@@ -31,10 +31,18 @@ Layout contract:
     the cleared (empty) rows reproduces the spilled content, and the
     row's BLOCKED capacity mask is re-imposed afterwards (merge relaxes
     rows to full width);
-  * quantile tenancy composes through the dyadic bank over composite
-    keys: per-tenant rank is a range difference inside the tenant's key
-    range (:func:`tenant_rank_many`), per-tenant quantiles a lockstep
-    search over the item part only (:func:`tenant_quantile_many`).
+  * per-tenant quantiles have a layout of their own
+    (``SketchSpec(kind='quantile', tenants=T)``, :class:`TenantLevels`):
+    tenant t owns one row per dyadic layer, rows ``t*bits + l``, fed
+    through ``bank.TenantLevelRouter``, so each tenant's rank error is
+    bounded by its own mass |F_t|₁ (:func:`tenant_ranks`,
+    :func:`tenant_quantiles`, every tenant's queries in one search);
+  * quantile tenancy also composes through ONE dyadic bank over
+    composite keys: per-tenant rank is a range difference inside the
+    tenant's key range (:func:`tenant_rank_many`), per-tenant quantiles
+    a lockstep search over the item part only
+    (:func:`tenant_quantile_many`), with an error bound of the whole
+    bank's mass.
 """
 from __future__ import annotations
 
@@ -53,9 +61,10 @@ from . import state as st
 from .blocks import block_update
 from .state import BLOCKED, EMPTY, SketchState, _INT_MAX
 
-# mirrors api.LAYOUT_FREQUENCY (api imports this module post-registry;
-# importing api here would be cyclic)
+# mirror api.LAYOUT_FREQUENCY / LAYOUT_QUANTILE (api imports this module
+# post-registry; importing api here would be cyclic)
 _LAYOUT_FREQUENCY = 1
+_LAYOUT_QUANTILE = 2
 
 
 # ---------------------------------------------------------------------------
@@ -434,6 +443,167 @@ def tenant_quantile_many(state: dy.DyadicState, tenant, qs: jax.Array,
 
 
 # ---------------------------------------------------------------------------
+# Per-tenant Dyadic SS±: each tenant's own layers, one bank row each
+# ---------------------------------------------------------------------------
+
+class TenantLevels(NamedTuple):
+    """Every tenant's dyadic layers in one ``(T*bits, k)`` bank.
+
+    Tenant t's layer l is row ``t*bits + l`` (``bank.TenantLevelRouter``)
+    and monitors ``x >> l`` of t's own values, tagged with its row;
+    ``mass[t]`` is t's exactly tracked |F_t|₁. Each row evolves as layer
+    l of a single-tenant ``dyadic.DyadicState`` fed only t's values.
+    """
+
+    bank: SketchState
+    mass: jax.Array    # (T,) int32
+
+    @property
+    def num_tenants(self) -> int:
+        return self.mass.shape[0]
+
+    @property
+    def bits(self) -> int:
+        return self.bank.ids.shape[0] // self.mass.shape[0]
+
+
+def init_levels(layer_caps: Sequence[int], num_tenants: int) -> TenantLevels:
+    """Empty per-tenant dyadic bank: each tenant's layer l holds
+    ``layer_caps[l]`` counters (BLOCKED padding up to the widest)."""
+    return TenantLevels(bank=bk.init(list(layer_caps) * num_tenants),
+                        mass=jnp.zeros((num_tenants,), jnp.int32))
+
+
+def update_levels(tl: TenantLevels, keys, weights,
+                  variant: int = 2) -> TenantLevels:
+    """One fused launch ingesting a composite-key block into every
+    tenant's layers: the block expands on the device into one row-tagged
+    key per layer (``TenantLevelRouter.expand``) and takes the tenant
+    bank's ingest over the ``T*bits`` rows."""
+    router = bk.TenantLevelRouter(tl.num_tenants, tl.bits)
+    keys = keys.astype(jnp.int32)
+    weights = weights.astype(jnp.int32)
+    tagged, w = router.expand(keys, weights)
+    mass = tl.mass.at[jnp.right_shift(keys, tl.bits)].add(weights,
+                                                         mode="drop")
+    return TenantLevels(
+        bank=bk.update_block_fused(tl.bank, tagged, w, router.rows, variant),
+        mass=mass)
+
+
+def _level_ranks(tl: TenantLevels, tenants: jax.Array,
+                 xs: jax.Array) -> jax.Array:
+    """rank(xs[i]) in tenant ``tenants[i]``'s own stream, both (n,).
+
+    ``dyadic.rank_many`` on the tenant's rows: layer l adds node
+    ``2*(y >> (l+1))`` iff bit l of y = x+1 is set, each layer's
+    estimate clamped at 0; y >= 2^bits is the tenant's whole mass.
+    """
+    bits = tl.bits
+    router = bk.TenantLevelRouter(tl.num_tenants, bits)
+    y = xs.astype(jnp.int32) + 1
+    lvl = jnp.arange(bits, dtype=jnp.int32)[None, :]
+    node = (2 * jnp.right_shift(y[:, None], lvl + 1)) & ((1 << bits) - 1)
+    take = (jnp.right_shift(y[:, None], lvl) & 1) > 0
+    rows = tenants.astype(jnp.int32)[:, None] * bits + lvl     # (n, bits)
+    est = bk.query_rows(tl.bank, rows.reshape(-1),
+                        router.key(rows, node).reshape(-1))
+    r = jnp.where(take, jnp.maximum(est.reshape(rows.shape), 0), 0).sum(1)
+    return jnp.where(y >= (1 << bits), tl.mass[tenants], r).astype(jnp.int32)
+
+
+@jax.jit
+def tenant_ranks(tl: TenantLevels, tenants: jax.Array,
+                 xs: jax.Array) -> jax.Array:
+    """Estimated rank(x) = |{v <= x}| of each tenant's own values:
+    ``tenants`` (n,), ``xs`` (n, m) -> (n, m). The error is at most
+    eps·|F_t|₁ of the tenant's own mass (paper §4.2)."""
+    n, m = xs.shape
+    lane_t = jnp.broadcast_to(tenants[:, None], (n, m)).reshape(-1)
+    return _level_ranks(tl, lane_t, xs.reshape(-1)).reshape(n, m)
+
+
+@jax.jit
+def tenant_quantiles(tl: TenantLevels, tenants: jax.Array,
+                     qs: jax.Array) -> jax.Array:
+    """Each tenant's quantiles: ``tenants`` (n,), ``qs`` (n, m) -> (n, m),
+    the smallest x with rank_t(x) >= q·|F_t|₁.
+
+    One ``dyadic.lockstep_quantile_search`` over all n·m lanes (bits+1
+    rounds; its float32 rank target), so a tenant's answer is the same
+    whether it is searched alone or beside others.
+    """
+    n, m = qs.shape
+    lane_t = jnp.broadcast_to(tenants[:, None], (n, m)).reshape(-1)
+    out = dy.lockstep_quantile_search(
+        lambda xs: _level_ranks(tl, lane_t, xs), tl.mass[lane_t], tl.bits,
+        qs.reshape(-1).astype(jnp.float32))
+    return out.reshape(n, m)
+
+
+class TenantLevelsAdapter:
+    """``SketchSpec(kind='quantile', tenants=T)``: per-tenant dyadic
+    layers (:class:`TenantLevels`). ``update`` reads the tenant count
+    from the state, as :class:`TenantAdapter` does. Ranks and quantiles
+    are per tenant (:func:`tenant_ranks`, :func:`tenant_quantiles`);
+    point queries read the key's tenant's leaf layer."""
+
+    def make(self, spec) -> TenantLevels:
+        return init_levels(spec.layer_capacities(), spec.tenants)
+
+    def update(self, spec, state, items, weights):
+        return update_levels(state, items, weights, spec.variant_id)
+
+    def query_many(self, spec, state, items):
+        keys = items.astype(jnp.int32)
+        router = bk.TenantLevelRouter(state.num_tenants, state.bits)
+        rows = jnp.right_shift(keys, router.bits) * router.bits
+        return bk.query_rows(state.bank, rows, router.key(
+            rows, keys & ((1 << router.bits) - 1)))
+
+    def topk(self, spec, state, m):
+        raise ValueError(
+            "the per-tenant quantile layout answers ranks and quantiles "
+            "per tenant (tenant_ranks / tenant_quantiles), not top-k")
+
+    def rank_many(self, spec, state, xs):
+        raise ValueError(
+            "ranks of the per-tenant quantile layout are per tenant: "
+            "repro.sketch.tenant.tenant_ranks / tenant_quantiles")
+
+    quantile_many = rank_many
+
+    def merge(self, spec, a, b):
+        return TenantLevels(bank=bk.merge_banks(a.bank, b.bank),
+                            mass=a.mass + b.mass)
+
+    def consolidate(self, spec, state):
+        return state
+
+    def save(self, spec, state) -> Dict[str, Any]:
+        return {
+            "layout": np.int32(_LAYOUT_QUANTILE),
+            "ids": np.asarray(state.bank.ids),
+            "counts": np.asarray(state.bank.counts),
+            "errors": np.asarray(state.bank.errors),
+            "mass": np.asarray(state.mass),
+            "tenants": np.int32(state.num_tenants),
+            "item_bits": np.int32(spec.bits),
+        }
+
+    def restore(self, spec, d) -> TenantLevels:
+        fields = SketchState(*(jnp.asarray(np.asarray(d[f]), jnp.int32)
+                               for f in ("ids", "counts", "errors")))
+        if fields.ids.shape[0] != spec.tenants * spec.bits:
+            raise ValueError(
+                f"checkpoint has {fields.ids.shape[0]} rows but the spec's "
+                f"layout (tenants={spec.tenants} x bits={spec.bits}) needs "
+                f"{spec.tenants * spec.bits}")
+        return TenantLevels(bank=fields, mass=jnp.asarray(
+            np.asarray(d["mass"]).reshape(-1), jnp.int32))
+
+
+# ---------------------------------------------------------------------------
 # Serial oracle: each row updated independently on its routed view
 # ---------------------------------------------------------------------------
 
@@ -591,6 +761,12 @@ __all__ = [
     "tenant_rank_many",
     "tenant_mass",
     "tenant_quantile_many",
+    "TenantLevels",
+    "TenantLevelsAdapter",
+    "init_levels",
+    "update_levels",
+    "tenant_ranks",
+    "tenant_quantiles",
     "reference_row_update",
     "update_serial_reference",
 ]

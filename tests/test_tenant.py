@@ -139,8 +139,11 @@ def test_route_dense_masks_foreign_weights():
 
 
 def test_spec_validation():
+    # quantile tenancy is its own layout: one row per (tenant, layer),
+    # so per-tenant capacity lists and hash shards are frequency-only
     with pytest.raises(ValueError, match="frequency"):
-        api.SketchSpec(kind="quantile", bits=8, eps=0.1, tenants=4)
+        api.SketchSpec(kind="quantile", bits=8, eps=0.1, tenants=4,
+                       shards=2)
     with pytest.raises(ValueError, match="tenant"):
         api.SketchSpec(kind="frequency", k=8, bits=8, tenant_caps=(4, 4))
     with pytest.raises(ValueError):

@@ -28,6 +28,9 @@ from repro.sketch.state import SketchState
 
 TENANTS, K_TENANT, BLOCK, BITS = 16384, 2048, 8192, 17
 HBM_BYTES = 16 * 2**30
+# the per-tenant Dyadic SS± layout: 2,048 tenants x 16 layer rows of the
+# widest layer's ceil(2*alpha*bits/eps) = 6,400 counters (eps 0.01)
+Q_TENANTS, Q_BITS, Q_K = 2048, 16, 6400
 
 
 @pytest.fixture(scope="module")
@@ -80,6 +83,40 @@ def test_tenant_bank_ingest(one_chip):
     state = 3 * TENANTS * K_TENANT * 4
     assert mem.alias_size_in_bytes >= state
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+def _level_bank(sharding):
+    shape = (Q_TENANTS * Q_BITS, Q_K)
+    return tn.TenantLevels(bank=SketchState(*[_sds(sharding, shape)] * 3),
+                           mass=_sds(sharding, (Q_TENANTS,)))
+
+
+def test_tenant_levels_ingest(one_chip):
+    """The per-tenant dyadic ingest at 2,048 tenants x 16 layers x 6,400:
+    the block expands 16-fold on the device, takes the touched-rows path,
+    compiles, fits one chip's HBM, and updates the bank in place."""
+    spec = api.SketchSpec(kind="quantile", tenants=Q_TENANTS, bits=Q_BITS,
+                          eps=0.01)
+    assert max(spec.layer_capacities()) == Q_K
+    assert bk.takes_touched(bk.TenantLevelRouter(Q_TENANTS, Q_BITS).rows,
+                            Q_BITS * BLOCK, Q_K)
+    ad = api.adapter_for(spec)
+    c = jax.jit(lambda s, i, w: ad.update(spec, s, i, w),
+                donate_argnums=(0,)).lower(
+        _level_bank(one_chip), _sds(one_chip, (BLOCK,)),
+        _sds(one_chip, (BLOCK,))).compile()
+    mem = c.memory_analysis()
+    state = 3 * Q_TENANTS * Q_BITS * Q_K * 4
+    assert mem.alias_size_in_bytes >= state
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+def test_tenant_quantiles_search(one_chip):
+    """The service's batched quantile search: 8 tenants x 3 quantiles in
+    one program at the full layout."""
+    jax.jit(tn.tenant_quantiles).lower(
+        _level_bank(one_chip), _sds(one_chip, (8,)),
+        _sds(one_chip, (8, 3), jnp.float32)).compile()
 
 
 @pytest.mark.parametrize("move", ["evict", "admit"])
